@@ -18,11 +18,11 @@ Compile time is reported first-class: compile_scalar_s / compile_batched_s
 per matrix plus their geomeans in the summary, and a compile-vs-run table
 (also written next to the JSON) — the level-bucketed factor trace lives or
 dies by this number.  ``--large`` adds the circuit_2000-scale matrices
-that only compile at all with the bucketed trace; ``--jax-cache DIR``
-points the persistent JAX compilation cache somewhere (default
-``$JAX_COMPILATION_CACHE_DIR`` or ``.jax_cache``; pass '' to disable —
-recorded compile numbers are only *cold* numbers with a fresh/disabled
-cache).
+that only compile at all with the bucketed trace.  The persistent JAX
+compilation cache lives where ``repro.launch.compile_cache`` says
+(``$JAX_COMPILATION_CACHE_DIR``, else ``<repo>/.jax_cache``);
+``--no-jax-cache`` turns it off — recorded compile numbers are only
+*cold* numbers with the cache off or empty.
 
 The ``analyze`` section records the host preprocessing phase per matrix
 (matching/ordering/symbolic/plan breakdown) plus plan-cache cold vs warm
@@ -32,17 +32,18 @@ interleaved circuit/banded/unsym mixed-pattern request stream through
 ``SolverService`` (cold analyze+compile vs warm cache hits, req/s).
 
 ``--devices N`` adds the multi-device sweep: the batched refactor+solve
-on a 1-D solver mesh over 1, 2, …, N (virtual CPU) devices
-(``HyluOptions(mesh=d)``), recorded as the ``devices_sweep`` section —
-batched refactor throughput (systems/s) vs device count.  Virtual
-devices are forced before jax initializes, so ``--devices`` must be
-handled by this process from the start (it is).
+on a 1-D solver mesh over 1, 2, …, N devices (``HyluOptions(mesh=d)``),
+recorded as the ``devices_sweep`` section — batched refactor throughput
+(systems/s) vs device count.  On a TPU host these are its chips; on the
+CPU platform ``launch.mesh.ensure_devices`` forces N virtual devices
+before jax initializes, so ``--devices`` is handled by this process from
+the start.
 
 Writes BENCH_repeated.json (per-matrix timings + geomean speedups over
 looped-ref) so successive PRs have a perf trajectory.
 
     PYTHONPATH=src python -m benchmarks.bench_factor_repeated \
-        [--k 32] [--quick] [--large] [--jax-cache DIR] [--devices N]
+        [--k 32] [--quick] [--large] [--no-jax-cache] [--devices N]
 """
 from __future__ import annotations
 
@@ -891,25 +892,26 @@ def main(argv=None):
                     help="amalgamation fill tolerance for the corpus lane "
                          "(HyluOptions.amalg_fill_tol)")
     ap.add_argument("--out", default="BENCH_repeated.json")
-    ap.add_argument("--jax-cache", default=None, metavar="DIR",
-                    help="persistent JAX compilation cache dir "
-                         "('' disables; default $JAX_COMPILATION_CACHE_DIR "
-                         "or .jax_cache)")
+    ap.add_argument("--no-jax-cache", action="store_true",
+                    help="turn the persistent JAX compilation cache off "
+                         "(cold compiles; the cache otherwise lives at "
+                         "$JAX_COMPILATION_CACHE_DIR or <repo>/.jax_cache)")
     ap.add_argument("--devices", type=int, default=None, metavar="N",
                     help="also sweep the sharded batched path over "
-                         "1..N (virtual CPU) devices -> devices_sweep "
-                         "section of the JSON")
+                         "1..N devices (a TPU host's chips, else virtual "
+                         "CPU devices) -> devices_sweep section of the "
+                         "JSON")
     ap.add_argument("--no-serving", action="store_true",
                     help="skip the mixed-pattern SolverService section")
     args = ap.parse_args(argv)
 
     if args.devices and args.devices > 1:
         # must happen before anything touches jax devices in this process
-        from repro.launch.mesh import ensure_virtual_cpu_devices
-        ensure_virtual_cpu_devices(args.devices)
+        from repro.launch.mesh import ensure_devices
+        ensure_devices(args.devices)
 
-    from ._jax_cache import enable_jax_compilation_cache
-    cache = enable_jax_compilation_cache(args.jax_cache)
+    from repro.launch.compile_cache import enable_compilation_cache
+    cache = enable_compilation_cache(not args.no_jax_cache)
     # pre-run state: a populated cache dir means the recorded compile
     # numbers may be warm-cache hits, not trajectory-grade cold compiles
     warm = bool(cache) and os.path.isdir(cache) and bool(os.listdir(cache))

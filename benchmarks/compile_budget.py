@@ -3,8 +3,8 @@ generous ceiling so O(nodes+edges) trace-size blowups can't silently
 return (the pre-bucketed engine took 70+ s here; the level-bucketed trace
 takes single-digit seconds).
 
-Runs with the persistent compilation cache pointed at a throwaway
-directory — the measurement must be a *cold* compile.
+Runs with the persistent compilation cache turned off — the measurement
+must be a *cold* compile.
 
     PYTHONPATH=src python -m benchmarks.compile_budget [--ceiling 120]
 """
@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import argparse
 import sys
-import tempfile
 import time
 
 import numpy as np
@@ -26,8 +25,9 @@ def main(argv=None):
 
     import jax
     jax.config.update("jax_enable_x64", True)
-    # fresh throwaway cache dir: never reuse a warm cache for the guard
-    jax.config.update("jax_compilation_cache_dir", tempfile.mkdtemp())
+    # never reuse a warm cache for the guard
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache(enabled=False)
 
     from repro.core.matrix import CSR
     from repro.core.api import analyze, factor, solve

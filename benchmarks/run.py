@@ -134,13 +134,13 @@ def main(argv=None):
                     help="K value sets for the repeated-solve engine bench")
     ap.add_argument("--no-repeated", action="store_true",
                     help="skip the jax/batched repeated-solve engine bench")
-    ap.add_argument("--jax-cache", default=None, metavar="DIR",
-                    help="persistent JAX compilation cache dir "
-                         "('' disables; default $JAX_COMPILATION_CACHE_DIR "
-                         "or .jax_cache)")
+    ap.add_argument("--no-jax-cache", action="store_true",
+                    help="turn the persistent JAX compilation cache off "
+                         "(it otherwise lives at $JAX_COMPILATION_CACHE_DIR "
+                         "or <repo>/.jax_cache)")
     args = ap.parse_args(argv)
-    from ._jax_cache import enable_jax_compilation_cache
-    cache = enable_jax_compilation_cache(args.jax_cache)
+    from repro.launch.compile_cache import enable_compilation_cache
+    cache = enable_compilation_cache(not args.no_jax_cache)
     if cache:
         print(f"[jax] persistent compilation cache at {cache}")
     figs = [int(f) for f in args.figures.split(",")]

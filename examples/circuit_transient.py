@@ -17,39 +17,29 @@ repeated-solve optimization.  The three paths:
 
 plus the multi-device finale: a T-step × K-corner sweep through the async
 double-buffered ``solve_sequence`` pipeline, sharded over the system-batch
-axis when more than one device is available (``--devices N`` forces N
-virtual CPU devices — it must be processed before jax initializes, which
-this script does) and with buffer donation keeping the refactor stream
-allocation-flat.
+axis when more than one device is available (``--devices N`` takes the
+chips of a TPU host, or forces N virtual CPU devices on the CPU platform
+before jax initializes, which this script does) and with buffer donation
+keeping the refactor stream allocation-flat.
 
     PYTHONPATH=src python examples/circuit_transient.py \
         [--n 240] [--steps 20] [--corners 32] [--devices 2]
 """
 import argparse
+import os
+import sys
 import time
 
 import numpy as np
 
-import os
-import sys
-
-# --devices must act before jax's CPU backend initializes
-_pre = argparse.ArgumentParser(add_help=False)
-_pre.add_argument("--devices", type=int, default=1)
-_pre_args, _ = _pre.parse_known_args()
-if _pre_args.devices > 1:
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={_pre_args.devices}")
-
-import jax  # noqa: E402
-
-jax.config.update("jax_enable_x64", True)
+import jax
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
 
 from repro.core import (CSR, HyluOptions, analyze, factor, refactor, solve,
                         solve_sequence)
+from repro.launch.compile_cache import enable_compilation_cache
+from repro.launch.mesh import ensure_devices
 
 
 def rc_network(n, seed=0):
@@ -94,9 +84,13 @@ def main(argv=None):
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--corners", type=int, default=32)
     ap.add_argument("--devices", type=int, default=1,
-                    help="shard the batched sweeps over N (virtual CPU) "
-                         "devices")
+                    help="shard the batched sweeps over N devices (a TPU "
+                         "host's chips, else virtual CPU devices)")
     args = ap.parse_args(argv)
+    if args.devices > 1:
+        ensure_devices(args.devices)    # before anything touches devices
+    jax.config.update("jax_enable_x64", True)
+    enable_compilation_cache()
     n, n_steps = args.n, args.steps
     dt = 1e-6
 

@@ -15,9 +15,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import CSR, analyze, make_sparse_solve
+from repro.launch.compile_cache import enable_compilation_cache
 
 
 def main():
+    enable_compilation_cache()
     rng = np.random.default_rng(0)
     n = 120
     # random resistor network (Laplacian + ground leaks)

@@ -27,23 +27,14 @@ import time
 
 import numpy as np
 
-# --devices must act before jax's CPU backend initializes
-_pre = argparse.ArgumentParser(add_help=False)
-_pre.add_argument("--devices", type=int, default=1)
-_pre_args, _ = _pre.parse_known_args()
-if _pre_args.devices > 1:
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={_pre_args.devices}")
-
-import jax  # noqa: E402
-
-jax.config.update("jax_enable_x64", True)
+import jax
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
 
-from repro.core import CSR, HyluOptions  # noqa: E402
-from repro.serve.solver_service import SolverService, SolveRequest  # noqa: E402
+from repro.core import CSR, HyluOptions
+from repro.launch.compile_cache import enable_compilation_cache
+from repro.launch.mesh import ensure_devices
+from repro.serve.solver_service import SolverService, SolveRequest
 
 
 def patterns(scale=1.0):
@@ -87,20 +78,24 @@ def run_window(svc, reqs, label):
 
 
 def main(argv=None):
-    ap = argparse.ArgumentParser(parents=[_pre])
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--devices", type=int, default=1,
+                    help="shard dispatches over N devices (a TPU host's "
+                         "chips, else virtual CPU devices)")
     ap.add_argument("--requests", type=int, default=24,
                     help="requests per serving window")
     ap.add_argument("--batch-size", type=int, default=8)
     ap.add_argument("--cache-dir", default="checkpoints/plan_cache_demo")
     ap.add_argument("--scale", type=float, default=1.0)
-    ap.add_argument("--jax-cache", default=None, metavar="DIR",
-                    help="persistent JAX compilation cache dir ('' "
-                         "disables) — with it, the 'fresh' window pays "
-                         "neither analyze nor compile")
+    ap.add_argument("--no-jax-cache", action="store_true",
+                    help="turn the persistent JAX compilation cache off "
+                         "(with it, the 'fresh' window pays neither "
+                         "analyze nor compile)")
     args = ap.parse_args(argv)
-
-    from _jax_cache import enable_jax_compilation_cache
-    jc = enable_jax_compilation_cache(args.jax_cache)
+    if args.devices > 1:
+        ensure_devices(args.devices)    # before anything touches devices
+    jax.config.update("jax_enable_x64", True)
+    jc = enable_compilation_cache(not args.no_jax_cache)
     if jc:
         print(f"[jax] persistent compilation cache at {jc}")
 

@@ -23,15 +23,13 @@ from .options import resolve_perturb_eps
 from .structure import build_solve_structure
 
 
-def make_sparse_solve(an: Analysis, dtype=jnp.float64, use_pallas: bool = False,
-                      interpret: bool = True):
+def make_sparse_solve(an: Analysis, dtype=jnp.float64, use_pallas: bool = False):
     """Emit the differentiable solver for a fixed sparsity pattern."""
     plan = an.plan
     ss = build_solve_structure(plan, bulk_min_width=an.opts.bulk_min_width)
     factor_fn = make_factor_fn(plan,
                                perturb_eps=resolve_perturb_eps(an.opts, dtype),
-                               dtype=dtype, use_pallas=use_pallas,
-                               interpret=interpret)
+                               dtype=dtype, use_pallas=use_pallas)
     lu_solve, lut_solve = make_lu_solver(ss, dtype=dtype)
 
     n = an.n

@@ -554,7 +554,10 @@ def _solve_sequence_pipelined(a_pattern, values_steps, b_steps,
             b_dev = _stage_rhs(eng, b_of(t + 1), k, copy=donate)
         outs.append((x, resid, n_iter, n_ref, stalled, failed))
         n_pert.append(jf.n_perturb)
-        prev = jf
+        # hold the factors only to donate them: otherwise step t's buffers
+        # would stay live through step t+1's refactor
+        prev = jf if donate else None
+        del jf
     jax.block_until_ready(outs[-1][0])           # the single sync point
     t_all = time.perf_counter() - t_all
 

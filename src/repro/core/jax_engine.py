@@ -15,7 +15,13 @@ Internal supernode factorization = dense partially-pivoted LU on the
 diagonal block (supernode diagonal pivoting + pivot perturbation).
 
 ``use_pallas=True`` routes panel updates through the Pallas kernels in
-``repro.kernels`` (interpret mode on CPU; compiled on real TPUs).
+``repro.kernels``, which run compiled on a TPU and interpreted elsewhere
+(:func:`repro.kernels.interpret_mode`).  Mosaic has no float64, so a
+Pallas engine on a TPU factors in float32 or narrower; asking for float64
+there fails at engine construction.
+
+Every matrix product asks for ``Precision.HIGHEST``: on a TPU the default
+would round float32 operands to bfloat16 before the MXU multiplies them.
 """
 from __future__ import annotations
 
@@ -26,7 +32,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_mode
+
 from .plan import FactorPlan
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _jit_donating(fn, donate_argnums):
@@ -59,18 +69,18 @@ def _trsm_upper_jax(u, x):
         acc = x[:, j]
         if j:
             yj = jnp.stack(cols, axis=1)            # (nr, j)
-            acc = acc - yj @ u[:j, j]
+            acc = acc - jnp.matmul(yj, u[:j, j], precision=_HIGHEST)
         cols.append(acc / u[j, j])
     return jnp.stack(cols, axis=1)
 
 
-def _panel_lu(panel, nr, lsize, eps_p, use_pallas=False, interpret=True):
+def _panel_lu(panel, nr, lsize, eps_p, use_pallas=False):
     """Dense LU of the diagonal block with partial pivoting within the
     supernode (supernode diagonal pivoting) + pivot perturbation.
     Returns (panel, local_perm, n_perturb)."""
     if use_pallas and nr > 1:
         from repro.kernels.panel import ops as panel_ops
-        return panel_ops.panel_lu(panel, nr, lsize, eps_p, interpret=interpret)
+        return panel_ops.panel_lu(panel, nr, lsize, eps_p)
     w = panel.shape[1]
     perm = jnp.arange(nr, dtype=jnp.int32)
     nper = jnp.int32(0)
@@ -111,7 +121,7 @@ def _panel_lu(panel, nr, lsize, eps_p, use_pallas=False, interpret=True):
 
 
 def _node_lu_writeback(vals, inode, nper, nd, panel, off, eps_p,
-                       use_pallas, interpret):
+                       use_pallas):
     """Internal LU of one node's (already edge-updated) panel + pivot
     bookkeeping + write-back.  Shared by the fully unrolled trace and the
     bucketed trace's narrow-level sequential nodes (whose edges were
@@ -120,7 +130,7 @@ def _node_lu_writeback(vals, inode, nper, nd, panel, off, eps_p,
     sizes — all offsets touched here are real."""
     nr = nd.nr
     panel, lperm, np_ = _panel_lu(panel, nr, nd.lsize, eps_p,
-                                  use_pallas=use_pallas, interpret=interpret)
+                                  use_pallas=use_pallas)
     nper = nper + np_
     if nr > 1:
         seg = jax.lax.dynamic_slice(inode, (nd.r0,), (nr,))
@@ -130,7 +140,7 @@ def _node_lu_writeback(vals, inode, nper, nd, panel, off, eps_p,
 
 
 def _node_step_unrolled(vals, inode, nper, nd, nodes, offs, eps_p,
-                        use_pallas, interpret):
+                        use_pallas):
     """One node's left-looking edge loop + internal LU (the per-node
     sequential kernel of the unrolled trace)."""
     off = int(offs[nd.nid])
@@ -151,17 +161,17 @@ def _node_step_unrolled(vals, inode, nper, nd, nodes, offs, eps_p,
         else:
             if use_pallas and nr > 1:
                 from repro.kernels.supsup import ops as supsup_ops
-                lts, xr = supsup_ops.supsup_update(
-                    x, src, k, interpret=interpret)
+                lts, xr = supsup_ops.supsup_update(x, src, k)
             else:
                 lts = _trsm_upper_jax(src[:, :k], x[:, :k])
-                xr = x[:, k:] - lts @ src[:, k:]
+                xr = x[:, k:] - jnp.matmul(lts, src[:, k:],
+                                           precision=_HIGHEST)
         panel = panel.at[:, cm].set(jnp.concatenate([lts, xr], axis=1))
     return _node_lu_writeback(vals, inode, nper, nd, panel, off, eps_p,
-                              use_pallas, interpret)
+                              use_pallas)
 
 
-def _panel_lu_bucketed(panels, wu, eps_p, use_pallas=False, interpret=True):
+def _panel_lu_bucketed(panels, wu, eps_p, use_pallas=False):
     """Dense LU with in-block partial pivoting on a (B, nr, wt) bucket of
     column-reordered panels: elimination runs over the static window
     [0, wu) (block + U suffix); trailing columns (the L prefix) only get
@@ -169,14 +179,13 @@ def _panel_lu_bucketed(panels, wu, eps_p, use_pallas=False, interpret=True):
     steps are exact no-ops.  Returns (panels, perm (B, nr), nper (B,))."""
     if use_pallas:
         from repro.kernels.panel import ops as panel_ops
-        return panel_ops.panel_lu_batched(panels, wu, eps_p,
-                                          interpret=interpret)
+        return panel_ops.panel_lu_batched(panels, wu, eps_p)
     from repro.kernels.panel.ref import panel_lu_bucketed_ref
     return panel_lu_bucketed_ref(panels, wu, eps_p)
 
 
 def _make_factor_fn_bucketed(plan: FactorPlan, perturb_eps, dtype,
-                             use_pallas, interpret, bulk_min_width=8):
+                             use_pallas, bulk_min_width=8):
     """Level-bucketed trace: O(levels × shape-buckets) XLA ops instead of
     O(nodes + edges).  Every level's edge applications run as batched
     per-bucket gathers + TRSM / GEMM + scatters; internal LUs are bucketed
@@ -214,8 +223,7 @@ def _make_factor_fn_bucketed(plan: FactorPlan, perturb_eps, dtype,
             for pb in step.panels:              # wider: bucketed dense LU
                 P = vals[jnp.asarray(pb.gather)]
                 P, perm, npb = _panel_lu_bucketed(
-                    P, pb.wu, eps_p, use_pallas=use_pallas,
-                    interpret=interpret)
+                    P, pb.wu, eps_p, use_pallas=use_pallas)
                 vals = vals.at[jnp.asarray(pb.scatter)].set(P)
                 nper = nper + jnp.sum(npb).astype(jnp.int32)
                 rows = jnp.asarray(pb.rows)
@@ -229,8 +237,7 @@ def _make_factor_fn_bucketed(plan: FactorPlan, perturb_eps, dtype,
                     vals, (off,), (nd.nr * nd.width,)).reshape(nd.nr,
                                                                nd.width)
                 vals, inode, nper = _node_lu_writeback(
-                    vals, inode, nper, nd, panel, off, eps_p,
-                    use_pallas, interpret)
+                    vals, inode, nper, nd, panel, off, eps_p, use_pallas)
             # ---- eager application of this level's outgoing edges --------
             for eb in step.edges:
                 S = vals[jnp.asarray(eb.src_idx)]     # (E, k, k+m)
@@ -242,13 +249,12 @@ def _make_factor_fn_bucketed(plan: FactorPlan, perturb_eps, dtype,
                 elif use_pallas:                      # sup-sup on Pallas
                     from repro.kernels.supsup import ops as supsup_ops
                     from repro.kernels.trisolve import ops as trisolve_ops
-                    lts = trisolve_ops.trsm_batched(U, X, interpret=interpret)
-                    delta = supsup_ops.gemm_batched(lts, Us,
-                                                    interpret=interpret)
+                    lts = trisolve_ops.trsm_batched(U, X)
+                    delta = supsup_ops.gemm_batched(lts, Us)
                 else:                                 # sup-sup via XLA
                     lts = jax.lax.linalg.triangular_solve(
                         U, X, left_side=False, lower=False)
-                    delta = lts @ Us
+                    delta = jnp.matmul(lts, Us, precision=_HIGHEST)
                 # one combined scatter: multiplier write-back expressed as
                 # an add of (lts - X), trailing update as -delta
                 ne = lts.shape[0]
@@ -287,8 +293,7 @@ def _make_factor_fn_bucketed(plan: FactorPlan, perturb_eps, dtype,
 
 def make_factor_fn(plan: FactorPlan, perturb_eps: float = 1e-8,
                    dtype=jnp.float64, use_pallas: bool = False,
-                   interpret: bool = True, schedule: str = "bucketed",
-                   bulk_min_width: int = 8):
+                   schedule: str = "bucketed", bulk_min_width: int = 8):
     """Emit the jittable numeric factorization for this plan.
 
     schedule="bucketed" (default) traces the level-bucketed program —
@@ -298,7 +303,7 @@ def make_factor_fn(plan: FactorPlan, perturb_eps: float = 1e-8,
     small plans)."""
     if schedule == "bucketed":
         return _make_factor_fn_bucketed(plan, perturb_eps, dtype,
-                                        use_pallas, interpret,
+                                        use_pallas,
                                         bulk_min_width=bulk_min_width)
     if schedule != "unrolled":
         raise ValueError(f"unknown factor schedule {schedule!r}: "
@@ -316,8 +321,7 @@ def make_factor_fn(plan: FactorPlan, perturb_eps: float = 1e-8,
         nper = jnp.int32(0)
         for nd in nodes:
             vals, inode, nper = _node_step_unrolled(
-                vals, inode, nper, nd, nodes, offs, eps_p,
-                use_pallas, interpret)
+                vals, inode, nper, nd, nodes, offs, eps_p, use_pallas)
         return JaxFactors(vals=vals, inode_perm=inode, n_perturb=nper)
 
     return factor_fn
@@ -445,107 +449,95 @@ def _tri_solve_batched(sched, vals, rhs, diag_slots=None):
     """Batched level-scheduled substitution: vals (K, slots), rhs (K, n) or
     (K, n, m) for multi-RHS.
 
-    Same schedule as ``_tri_solve`` — bulk levels unrolled (one product and
-    one duplicate-index scatter-add per level), narrow tail levels as
-    chunked ``lax.scan``s — with every op vectorized over the batch (and
-    any trailing RHS dim) as well.  Everything stays in the batch-first
-    layout: the reduction is a scatter-add on axis 1, not a segment-sum,
-    so no per-level ``moveaxis`` round-trips materialize (K, nnz)
-    transposes."""
+    Same schedule as ``_tri_solve``, but every level runs inside the
+    chunked ``lax.scan``s of ``_tri_scan_chunks`` — none is unrolled.  This
+    substitution runs inside the fused refinement ``while_loop``, where
+    each unrolled level adds its own constant index tables to the loop
+    body; on a TPU v5e, fem2d_10k's body with 424 unrolled levels did not
+    finish in 280 s, while circuit_10k's scanned one ran.  The scalar
+    ``_tri_solve`` keeps its unrolled bulk head: it runs outside any loop,
+    once per call.  Every op is vectorized over the batch (and any
+    trailing RHS dim) and stays batch-first: the reduction is a
+    scatter-add on axis 1, so no per-level ``moveaxis`` round-trips
+    materialize (K, nnz) transposes."""
     n = rhs.shape[1]
-    n_head, chunks = _tri_scan_chunks(sched, n)
+    _, chunks = _tri_scan_chunks(sched, n, bulk_min_width=n + 1)
     w = rhs
     multi = w.ndim == 3
-    for rows, cols, slot, seg in zip(sched.rows[:n_head],
-                                     sched.cols[:n_head],
-                                     sched.slot[:n_head],
-                                     sched.seg[:n_head]):
-        if len(cols):
-            v = vals[:, slot]
-            prod = v[:, :, None] * w[:, cols] if multi else v * w[:, cols]
-        if diag_slots is None:          # unit-diagonal L
-            if len(cols):               # one fused scatter: deps → rows
-                w = w.at[:, rows[seg]].add(-prod)
-        else:                           # non-unit diagonal U
-            d = vals[:, diag_slots[rows]]
+    if diag_slots is not None:
+        dpad = jnp.asarray(np.concatenate([diag_slots, diag_slots[:1]]))
+    w = jnp.concatenate(
+        [w, jnp.zeros(w.shape[:1] + (1,) + w.shape[2:], w.dtype)], axis=1)
+
+    def body(w, xs):
+        rows_l, rowmap_l, cols_l, slot_l = xs
+        v = vals[:, slot_l]
+        prod = v[:, :, None] * w[:, cols_l] if multi else v * w[:, cols_l]
+        w = w.at[:, rowmap_l].add(-prod)
+        if diag_slots is not None:
+            d = vals[:, dpad[rows_l]]
             if multi:
                 d = d[:, :, None]
-            if len(cols):
-                w = w.at[:, rows[seg]].add(-prod)
-            w = w.at[:, rows].divide(d)
-    if chunks:
-        if diag_slots is not None:
-            dpad = jnp.asarray(np.concatenate([diag_slots, diag_slots[:1]]))
-        w = jnp.concatenate(
-            [w, jnp.zeros(w.shape[:1] + (1,) + w.shape[2:], w.dtype)],
-            axis=1)
+            w = w.at[:, rows_l].divide(d)
+        return w, None
 
-        def body(w, xs):
-            rows_l, rowmap_l, cols_l, slot_l = xs
-            v = vals[:, slot_l]
-            prod = v[:, :, None] * w[:, cols_l] if multi else v * w[:, cols_l]
-            w = w.at[:, rowmap_l].add(-prod)
-            if diag_slots is not None:
-                d = vals[:, dpad[rows_l]]
-                if multi:
-                    d = d[:, :, None]
-                w = w.at[:, rows_l].divide(d)
-            return w, None
-
-        for ch in chunks:
-            w, _ = jax.lax.scan(body, w, tuple(jnp.asarray(a) for a in ch))
-        w = w[:, :n]
-    return w
+    for ch in chunks:
+        w, _ = jax.lax.scan(body, w, tuple(jnp.asarray(a) for a in ch))
+    return w[:, :n]
 
 
-def _block_lu_solve_batched(blocks, vals, c, interpret=True):
-    """Batched L U w = c following the node-block schedule: per node one
-    dense GEMV against the L-prefix/U-suffix rectangle plus a dense
-    triangular solve of the diagonal block — routed through the Pallas TRSM
-    (``kernels/trisolve``) for supernodes.  This is the ``use_pallas``
-    substitution path; width-1 nodes degenerate to scalar ops."""
+def _block_solve_batched(chunks, vals, rhs, upper: bool):
+    """Batched block substitution following ``block_solve_schedule``
+    chunks: per node level, one scatter-add of every off-block dependency,
+    then the width-1 diagonal divides (U only) and one Pallas TRSM over
+    all of the level's dense diagonal blocks (``kernels/trisolve``).
+    vals (K, slots); rhs (K, n) or (K, n, m)."""
     from repro.kernels.trisolve import ops as trisolve_ops
 
-    multi = c.ndim == 3
-    w = c if multi else c[..., None]
-    for nd in blocks:                               # forward: unit-lower L
-        b_blk = w[:, nd.r0:nd.r0 + nd.nr]
-        if nd.pre_cols.size:
-            b_blk = b_blk - jnp.einsum("kns,ksm->knm",
-                                       vals[:, nd.pre_slots],
-                                       w[:, nd.pre_cols])
-        if nd.nr > 1:
-            b_blk = trisolve_ops.trsm_left_unit_lower_batched(
-                vals[:, nd.blk_slots], b_blk, interpret=interpret)
-        w = w.at[:, nd.r0:nd.r0 + nd.nr].set(b_blk)
-    for nd in reversed(blocks):                     # backward: upper U
-        b_blk = w[:, nd.r0:nd.r0 + nd.nr]
-        if nd.suf_cols.size:
-            b_blk = b_blk - jnp.einsum("kns,ksm->knm",
-                                       vals[:, nd.suf_slots],
-                                       w[:, nd.suf_cols])
-        blk = vals[:, nd.blk_slots]
-        if nd.nr > 1:
-            b_blk = trisolve_ops.trsm_left_upper_batched(
-                blk, b_blk, interpret=interpret)
-        else:
-            b_blk = b_blk / blk[:, :, 0:1]
-        w = w.at[:, nd.r0:nd.r0 + nd.nr].set(b_blk)
+    solve = (trisolve_ops.trsm_left_upper_batched if upper
+             else trisolve_ops.trsm_left_unit_lower_batched)
+    multi = rhs.ndim == 3
+    w = rhs if multi else rhs[..., None]
+    kb, n, m = w.shape
+    w = jnp.concatenate([w, jnp.zeros((kb, 1, m), w.dtype)], axis=1)
+    v = jnp.concatenate([vals, jnp.zeros((kb, 1), vals.dtype),
+                         jnp.ones((kb, 1), vals.dtype)], axis=1)
+
+    def body(w, xs):
+        dep_rows, dep_cols, dep_slots, w1_rows, w1_slots, blk_rows, \
+            blk_slots = xs
+        w = w.at[:, dep_rows].add(-(v[:, dep_slots][..., None]
+                                    * w[:, dep_cols]))
+        if upper:
+            w = w.at[:, w1_rows].divide(v[:, w1_slots][..., None])
+        bs, r = blk_rows.shape
+        if bs:
+            y = solve(v[:, blk_slots].reshape(kb * bs, r, r),
+                      w[:, blk_rows].reshape(kb * bs, r, m))
+            w = w.at[:, blk_rows].set(y.reshape(kb, bs, r, m))
+        return w, None
+
+    for ch in chunks:
+        w, _ = jax.lax.scan(body, w,
+                            tuple(jnp.asarray(a) for a in ch.arrays()))
+    w = w[:, :n]
     return w if multi else w[..., 0]
 
 
-def make_batched_lu_solver(ss, dtype=jnp.float64, use_pallas: bool = False,
-                           interpret: bool = True):
+def make_batched_lu_solver(ss, dtype=jnp.float64, blocks=None):
     """Batched variant of :func:`make_lu_solver` over (K, slots)/(K, n)
-    (or (K, n, m) multi-RHS).  ``use_pallas=True`` swaps the level-scheduled
-    scatter-add substitution for the node-block schedule whose supernode
-    diagonal blocks run on the Pallas TRSM kernel."""
-    if use_pallas:
-        def lu_solve_batched(vals, c):
-            return _block_lu_solve_batched(ss.blocks, vals,
-                                           c.astype(vals.dtype),
-                                           interpret=interpret)
-        return lu_solve_batched
+    (or (K, n, m) multi-RHS).  ``blocks`` — the forward and backward
+    ``block_solve_schedule`` of a ``use_pallas`` engine — swaps the
+    level-scheduled scatter-add substitution for the node-block one whose
+    supernode diagonal blocks run on the Pallas TRSM kernel."""
+    if blocks is not None:
+        fwd, bwd = blocks
+
+        def block_lu_solve_batched(vals, c):
+            y = _block_solve_batched(fwd, vals, c.astype(vals.dtype),
+                                     upper=False)
+            return _block_solve_batched(bwd, vals, y, upper=True)
+        return block_lu_solve_batched
 
     def lu_solve_batched(vals, c):
         y = _tri_solve_batched(ss.l_fwd, vals, c.astype(vals.dtype))
@@ -634,10 +626,10 @@ class RepeatedSolveEngine:
                                               + LU substitution fused)
       apply_batched(vals, inode, B)    -> X   (K, n) — or (K, n, m) for
                                               multi-RHS — via the natively
-                                              batched tri-solve (scatter-add
-                                              levels + scanned narrow tail,
-                                              or the Pallas-TRSM node-block
-                                              path when ``use_pallas=True``);
+                                              batched tri-solve (scanned
+                                              scatter-add levels, or the
+                                              Pallas-TRSM node-block path
+                                              when ``use_pallas=True``);
                                               always single-device (it is the
                                               host-loop oracle path)
       refined_batched_solver(ip, ix)   -> the *fused* batched solve:
@@ -665,8 +657,7 @@ class RepeatedSolveEngine:
     def __init__(self, plan: FactorPlan, ss, *, src_map, scale_map, p, q,
                  row_scale, col_scale, perturb_eps: float = 1e-8,
                  dtype=jnp.float64, refine_dtype=None,
-                 use_pallas: bool = False,
-                 interpret: bool = True, schedule: str = "bucketed",
+                 use_pallas: bool = False, schedule: str = "bucketed",
                  bulk_min_width: int = 8, mesh=None):
         if refine_dtype is None:
             # mirror options.resolve_dtype_names: residual/solution
@@ -684,6 +675,13 @@ class RepeatedSolveEngine:
                     "disabled — run jax.config.update('jax_enable_x64', "
                     "True) before building the engine, or request "
                     "dtype=jnp.float32 explicitly")
+        if (use_pallas and np.dtype(dtype) == np.float64
+                and not interpret_mode()):
+            raise ValueError(
+                "use_pallas=True compiles the Pallas kernels with Mosaic on "
+                f"this {jax.default_backend()} backend, and Mosaic has no "
+                "float64 — use factor_dtype='float32' (refinement still "
+                "accumulates in float64) or use_pallas=False")
         self.n = plan.n
         self.dtype = dtype             # factor-panel/substitution dtype
         self.factor_dtype = dtype
@@ -694,13 +692,15 @@ class RepeatedSolveEngine:
         self.plan = plan
         self.bulk_min_width = bulk_min_width
         factor_fn = make_factor_fn(plan, perturb_eps=perturb_eps, dtype=dtype,
-                                   use_pallas=use_pallas, interpret=interpret,
-                                   schedule=schedule,
+                                   use_pallas=use_pallas, schedule=schedule,
                                    bulk_min_width=bulk_min_width)
         lu_solve, lut_solve = make_lu_solver(ss, dtype=dtype)
-        lu_solve_b = make_batched_lu_solver(ss, dtype=dtype,
-                                            use_pallas=use_pallas,
-                                            interpret=interpret)
+        blocks = None
+        if use_pallas:
+            from .structure import block_solve_schedule
+            blocks = (block_solve_schedule(plan, upper=False),
+                      block_solve_schedule(plan, upper=True))
+        lu_solve_b = make_batched_lu_solver(ss, dtype=dtype, blocks=blocks)
         src = jnp.asarray(src_map)
         scl = jnp.asarray(scale_map, dtype=dtype)
         p_ = jnp.asarray(p)
@@ -733,21 +733,20 @@ class RepeatedSolveEngine:
         self.n_shards = int(mesh.size) if mesh is not None else 1
         refactor_b = jax.vmap(_refactor)
         if mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import NamedSharding, PartitionSpec
 
             spec = PartitionSpec(self.batch_axis)
             #: the sharding batched inputs should be staged with (device_put
             #: here = no resharding inside the jitted calls)
             self.batch_sharding = NamedSharding(mesh, spec)
-            # check_rep=False: the factor/while-loop primitives have no
-            # replication rule on this jax version; nothing here is
-            # replicated anyway (every output is batch-sharded)
-            refactor_b = shard_map(
+            # check_vma=False: the per-system programs never mix shards,
+            # and the loop carries built from constants would otherwise
+            # each need a pcast to "varying"
+            refactor_b = jax.shard_map(
                 refactor_b, mesh=mesh, in_specs=(spec,),
                 out_specs=JaxFactors(vals=spec, inode_perm=spec,
                                      n_perturb=spec),
-                check_rep=False)
+                check_vma=False)
         else:
             self.batch_sharding = None
         self._refactor_batched_impl = refactor_b
@@ -888,18 +887,16 @@ class RepeatedSolveEngine:
 
         fn = solve_refined
         if self.mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec
 
             spec = PartitionSpec(batch_axis)
             rep = PartitionSpec()
-            # check_rep=False: lax.while_loop has no replication rule on
-            # this jax version; n_iter is the one P() output and the pmax
-            # above makes it genuinely replicated
-            fn = shard_map(fn, mesh=self.mesh,
-                           in_specs=(spec, spec, spec, spec, rep, rep),
-                           out_specs=(spec, spec, rep, spec, spec, spec),
-                           check_rep=False)
+            # check_vma=False as for refactor_batched; n_iter is the one
+            # P() output, and the pmax above makes it genuinely replicated
+            fn = jax.shard_map(fn, mesh=self.mesh,
+                               in_specs=(spec, spec, spec, spec, rep, rep),
+                               out_specs=(spec, spec, rep, spec, spec, spec),
+                               check_vma=False)
         solver = (_jit_donating(fn, donate_argnums=(2, 3)) if donate
                   else jax.jit(fn))
         self._refined_cache[key] = solver

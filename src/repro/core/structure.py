@@ -232,40 +232,116 @@ def tri_schedule(n, indptr, indices, slots, lower: bool,
 
 
 @dataclasses.dataclass
-class BlockNode:
-    """Static per-node gather maps for the block (panel) substitution path.
+class BlockSolveChunk:
+    """One ``lax.scan`` of the block substitution (the ``use_pallas`` solve
+    path): a run of node levels padded to shared shapes, leading dim =
+    levels.  Per level, every node's off-block dependencies (its L prefix
+    going forward, its U suffix going backward) are flat (row, col, slot)
+    triples; width-1 nodes keep their diagonal slot; wider nodes gather
+    their dense diagonal block for the Pallas TRSM.
 
-    The dense diagonal block (``blk_slots``) and the off-block L-prefix /
-    U-suffix rectangles are read straight out of the flat panel buffer with
-    these compile-time index matrices, so a solve can run node-by-node as
-    dense GEMV/TRSM ops — the shape the Pallas TRSM kernel wants — instead
-    of row-by-row levels."""
-    r0: int
-    nr: int
-    pre_cols: np.ndarray    # (lsize,)  global cols of the L prefix
-    pre_slots: np.ndarray   # (nr, lsize) flat slots of the L prefix
-    suf_cols: np.ndarray    # (usize,)  global cols of the U suffix
-    suf_slots: np.ndarray   # (nr, usize) flat slots of the U suffix
-    blk_slots: np.ndarray   # (nr, nr) flat slots of the dense diagonal block
-                            # (strict lower = L values, upper incl. diag = U)
+    Padding is maskless: padded rows and columns point at the extra row n
+    of the padded unknown vector, which stays 0, and padded slots at the
+    zero (``total_slots``) or the one (``total_slots + 1``) appended past
+    the value buffer — ones on padded block diagonals, so padded unknowns
+    solve to 0."""
+    dep_rows: np.ndarray    # (L, D)
+    dep_cols: np.ndarray    # (L, D)
+    dep_slots: np.ndarray   # (L, D)
+    w1_rows: np.ndarray     # (L, B1)       rows of width-1 nodes
+    w1_slots: np.ndarray    # (L, B1)       their diagonal slots
+    blk_rows: np.ndarray    # (L, Bs, R)    rows of wider nodes
+    blk_slots: np.ndarray   # (L, Bs, R, R) their diagonal blocks
+
+    def arrays(self) -> tuple:
+        return (self.dep_rows, self.dep_cols, self.dep_slots, self.w1_rows,
+                self.w1_slots, self.blk_rows, self.blk_slots)
 
 
-def block_schedule(plan: FactorPlan) -> list:
-    """Per-node block maps, ascending r0 (forward L order; reverse for U)."""
-    nodes = []
-    for nd in plan.nodes:
+def block_solve_schedule(plan: FactorPlan, upper: bool,
+                         max_groups: int = 24) -> list:
+    """The node-level schedule of one block substitution — forward unit-L
+    (``upper=False``) or backward U — as a list of ``BlockSolveChunk``s in
+    execution order.
+
+    A node's level is one past the deepest level among the nodes owning
+    its off-block columns, so same-level nodes are independent.  Runs of
+    consecutive levels share one padded shape (``segment_levels`` over
+    (deps, width-1 nodes, wider nodes, block size)), so the traced program
+    grows with the number of chunks — at most ``max_groups`` — not with
+    the number of nodes."""
+    n, zero, one = plan.n, plan.total_slots, plan.total_slots + 1
+    nodes = plan.nodes
+    owner = np.empty(n, np.int64)
+    for i, nd in enumerate(nodes):
+        owner[nd.r0:nd.r1] = i
+    lev = np.zeros(len(nodes), np.int64)
+    per_node = []
+    order = range(len(nodes) - 1, -1, -1) if upper else range(len(nodes))
+    for i in order:
+        nd = nodes[i]
         off = int(plan.panel_offset[nd.nid])
         nr, w, ls = nd.nr, nd.width, nd.lsize
-        row = off + np.arange(nr, dtype=np.int64)[:, None] * w
-        nodes.append(BlockNode(
-            r0=nd.r0, nr=nr,
-            pre_cols=nd.pattern[:ls].astype(np.int64),
-            pre_slots=row + np.arange(ls, dtype=np.int64)[None, :],
-            suf_cols=nd.pattern[ls + nr:].astype(np.int64),
-            suf_slots=row + ls + nr + np.arange(nd.usize, dtype=np.int64)[None, :],
-            blk_slots=row + ls + np.arange(nr, dtype=np.int64)[None, :],
-        ))
-    return nodes
+        base = off + np.arange(nr, dtype=np.int64)[:, None] * w
+        if upper:
+            cols = nd.pattern[ls + nr:].astype(np.int64)
+            slots = base + ls + nr + np.arange(len(cols))[None, :]
+        else:
+            cols = nd.pattern[:ls].astype(np.int64)
+            slots = base + np.arange(ls)[None, :]
+        if len(cols):
+            lev[i] = 1 + lev[owner[cols]].max()
+        per_node.append((i, cols, slots, base + ls + np.arange(nr)[None, :]))
+    levels: dict = {}
+    for i, cols, slots, blk in per_node:
+        levels.setdefault(int(lev[i]), []).append((nodes[i], cols, slots,
+                                                   blk))
+    levels = [levels[k] for k in sorted(levels)]
+
+    def _dims(level):
+        wide = [nd.nr for nd, *_ in level if nd.nr > 1]
+        return (sum(nd.nr * len(c) for nd, c, *_ in level),
+                len(level) - len(wide), len(wide), max(wide, default=1))
+
+    chunks = []
+    for i0, i1 in segment_levels([_dims(lv) for lv in levels], max_groups):
+        group = levels[i0:i1]
+        dims = [_dims(lv) for lv in group]
+        nl = len(group)
+        d = max(max(x[0] for x in dims), 1)
+        b1 = max(max(x[1] for x in dims), 1)
+        bs = max(x[2] for x in dims)
+        r = max(x[3] for x in dims) if bs else 1
+        ch = BlockSolveChunk(
+            dep_rows=np.full((nl, d), n, np.int32),
+            dep_cols=np.full((nl, d), n, np.int32),
+            dep_slots=np.full((nl, d), zero, np.int32),
+            w1_rows=np.full((nl, b1), n, np.int32),
+            w1_slots=np.full((nl, b1), one, np.int32),
+            blk_rows=np.full((nl, bs, r), n, np.int32),
+            blk_slots=np.broadcast_to(
+                np.where(np.eye(r, dtype=bool), one, zero).astype(np.int32),
+                (nl, bs, r, r)).copy())
+        for li, level in enumerate(group):
+            dpos = j1 = js = 0
+            for nd, cols, slots, blk in level:
+                nr = nd.nr
+                cnt = nr * len(cols)
+                ch.dep_rows[li, dpos:dpos + cnt] = np.repeat(
+                    np.arange(nd.r0, nd.r1), len(cols))
+                ch.dep_cols[li, dpos:dpos + cnt] = np.tile(cols, nr)
+                ch.dep_slots[li, dpos:dpos + cnt] = slots.ravel()
+                dpos += cnt
+                if nr == 1:
+                    ch.w1_rows[li, j1] = nd.r0
+                    ch.w1_slots[li, j1] = blk[0, 0]
+                    j1 += 1
+                else:
+                    ch.blk_rows[li, js, :nr] = np.arange(nd.r0, nd.r1)
+                    ch.blk_slots[li, js, :nr, :nr] = blk
+                    js += 1
+        chunks.append(ch)
+    return chunks
 
 
 # --------------------------------------------------------------------------
@@ -714,7 +790,6 @@ class SolveStructure:
     u_bwd: TriSched       # U w = y      (backward)
     lt_bwd: TriSched      # Lᵀ w = y     (backward; adjoint path)
     ut_fwd: TriSched      # Uᵀ y = c     (forward;  adjoint path)
-    blocks: list          # list[BlockNode] — dense-block path (Pallas TRSM)
 
 
 def build_solve_structure(plan: FactorPlan, bulk_min_width: int = 8) -> SolveStructure:
@@ -731,5 +806,4 @@ def build_solve_structure(plan: FactorPlan, bulk_min_width: int = 8) -> SolveStr
     ut_fwd = tri_schedule(n, ut_ip, ut_ix, ut_sl, lower=True,
                           bulk_min_width=bulk_min_width)
     return SolveStructure(n=n, lu=lu, l_fwd=l_fwd, u_bwd=u_bwd,
-                          lt_bwd=lt_bwd, ut_fwd=ut_fwd,
-                          blocks=block_schedule(plan))
+                          lt_bwd=lt_bwd, ut_fwd=ut_fwd)

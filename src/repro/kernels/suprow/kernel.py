@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 
 def _suprow_kernel(xk_ref, xm_ref, u_ref, b_ref, y_ref, xr_ref, y_scr, *,
                    k: int):
@@ -39,7 +41,7 @@ def _suprow_kernel(xk_ref, xm_ref, u_ref, b_ref, y_ref, xr_ref, y_scr, *,
 
 @functools.partial(jax.jit, static_argnames=("tn", "interpret"))
 def suprow_update_p(xk: jax.Array, xm: jax.Array, u: jax.Array, b: jax.Array,
-                    tn: int = 512, interpret: bool = True):
+                    tn: int = 512, interpret: bool | None = None):
     """xk: (1,k) row head; xm: (1,m) row tail; u: (k,k); b: (k,m)."""
     k = u.shape[0]
     m = xm.shape[1]
@@ -63,5 +65,5 @@ def suprow_update_p(xk: jax.Array, xm: jax.Array, u: jax.Array, b: jax.Array,
             jax.ShapeDtypeStruct((1, m), xm.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((1, k), xk.dtype)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xk, xm, u, b)

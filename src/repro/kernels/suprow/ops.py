@@ -8,8 +8,7 @@ from .ref import suprow_update_ref
 __all__ = ["suprow_update", "suprow_update_ref"]
 
 
-def suprow_update(x: jax.Array, src: jax.Array, k: int,
-                  interpret: bool = True):
+def suprow_update(x: jax.Array, src: jax.Array, k: int):
     """x: (k+m,) target row slice; src: (k, k+m). Returns (y, xr)."""
     m = x.shape[0] - k
 
@@ -21,5 +20,5 @@ def suprow_update(x: jax.Array, src: jax.Array, k: int,
     b = jnp.zeros((kp, mp), x.dtype).at[:k, :m].set(src[:, k:])
     xk = jnp.zeros((1, kp), x.dtype).at[0, :k].set(x[:k])
     xm = jnp.zeros((1, mp), x.dtype).at[0, :m].set(x[k:])
-    y, xr = suprow_update_p(xk, xm, u, b, interpret=interpret)
+    y, xr = suprow_update_p(xk, xm, u, b)
     return y[0, :k], xr[0, :m]

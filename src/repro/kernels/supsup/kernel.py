@@ -17,13 +17,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import I0, interpret_mode
+
+# f32 operands multiply at full f32 precision on the MXU (the default
+# would round them to bf16 first); f64 exists only in CPU interpretation
+_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _acc_dtype(dtype):
+    """Accumulator dtype: float32 for float32/bfloat16 operands, float64
+    only for the interpreter's float64 operands (Mosaic has no float64)."""
+    return jnp.promote_types(dtype, jnp.float32)
+
 
 def _gemm_update_kernel(c_ref, a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = c_ref[...].astype(acc_ref.dtype)
 
-    acc_ref[...] -= jnp.dot(a_ref[...], b_ref[...],
+    acc_ref[...] -= jnp.dot(a_ref[...], b_ref[...], precision=_PRECISION,
                             preferred_element_type=acc_ref.dtype)
 
     @pl.when(pl.program_id(2) == n_k - 1)
@@ -36,7 +48,7 @@ def _bmm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[0], b_ref[0],
+    acc_ref[...] += jnp.dot(a_ref[0], b_ref[0], precision=_PRECISION,
                             preferred_element_type=acc_ref.dtype)
 
     @pl.when(pl.program_id(2) == n_k - 1)
@@ -46,12 +58,14 @@ def _bmm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
 
 @functools.partial(jax.jit, static_argnames=("tn", "tk", "interpret"))
 def bmm(a: jax.Array, b: jax.Array, tn: int = 128, tk: int = 128,
-        interpret: bool = True) -> jax.Array:
+        interpret: bool | None = None) -> jax.Array:
     """Batched A @ B for the bucketed sup-sup update: a (E, nr, k),
     b (E, k, m) → (E, nr, m).  The leading bucket dim is the outer Pallas
     grid axis; each bucket member's GEMM tiles its m/k dims into VMEM with
     a scratch accumulator over the contraction axis (the nr dim of one
-    supernode edge is ≤ 128 and stays whole)."""
+    supernode edge is ≤ 128 and stays whole).  k must be a multiple of
+    the contraction tile (or at most one tile): a partial contraction
+    block would sum over out-of-bounds garbage."""
     E, nr, k = a.shape
     m = b.shape[2]
     tn, tk = min(tn, m), min(tk, k)
@@ -60,15 +74,13 @@ def bmm(a: jax.Array, b: jax.Array, tn: int = 128, tk: int = 128,
         functools.partial(_bmm_kernel, n_k=grid[2]),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, nr, tk), lambda e, j, l: (e, 0, l)),   # A
+            pl.BlockSpec((1, nr, tk), lambda e, j, l: (e, I0, l)),   # A
             pl.BlockSpec((1, tk, tn), lambda e, j, l: (e, l, j)),   # B
         ],
-        out_specs=pl.BlockSpec((1, nr, tn), lambda e, j, l: (e, 0, j)),
+        out_specs=pl.BlockSpec((1, nr, tn), lambda e, j, l: (e, I0, j)),
         out_shape=jax.ShapeDtypeStruct((E, nr, m), a.dtype),
-        # fp32 accumulation on the MXU; f64 only in CPU-interpret testing
-        scratch_shapes=[pltpu.VMEM(
-            (nr, tn), jnp.float64 if a.dtype == jnp.float64 else jnp.float32)],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((nr, tn), _acc_dtype(a.dtype))],
+        interpret=interpret_mode(interpret),
     )(a, b)
 
 
@@ -76,8 +88,10 @@ def bmm(a: jax.Array, b: jax.Array, tn: int = 128, tk: int = 128,
                    static_argnames=("tm", "tn", "tk", "interpret"))
 def gemm_update(c: jax.Array, a: jax.Array, b: jax.Array,
                 tm: int = 128, tn: int = 128, tk: int = 128,
-                interpret: bool = True) -> jax.Array:
-    """C - A @ B with VMEM tiling. c: (nr, m), a: (nr, k), b: (k, m)."""
+                interpret: bool | None = None) -> jax.Array:
+    """C - A @ B with VMEM tiling. c: (nr, m), a: (nr, k), b: (k, m); k
+    a multiple of the contraction tile or at most one tile (see
+    :func:`bmm`)."""
     nr, m = c.shape
     k = a.shape[1]
     tm, tn, tk = min(tm, nr), min(tn, m), min(tk, k)
@@ -92,8 +106,6 @@ def gemm_update(c: jax.Array, a: jax.Array, b: jax.Array,
         ],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nr, m), c.dtype),
-        # fp32 accumulation on the MXU; f64 only in CPU-interpret testing
-        scratch_shapes=[pltpu.VMEM(
-            (tm, tn), jnp.float64 if c.dtype == jnp.float64 else jnp.float32)],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((tm, tn), _acc_dtype(c.dtype))],
+        interpret=interpret_mode(interpret),
     )(c, a, b)

@@ -11,8 +11,9 @@ Two families live here:
   batched repeated-solve engine shards over — the K independent systems of
   ``factor_batched`` / ``solve_batched`` / ``solve_sequence`` are
   embarrassingly parallel, so a single data axis is the whole story — plus
-  the virtual-CPU-device harness (``ensure_virtual_cpu_devices``) that lets
-  tests and CI exercise multi-device sharding on one host.
+  ``ensure_devices``, which gives a script N devices: the chips of a TPU
+  host, or N virtual CPU devices (``ensure_virtual_cpu_devices``) so that
+  tests and CI exercise multi-device sharding on one CPU host.
 """
 from __future__ import annotations
 
@@ -24,21 +25,16 @@ import jax
 BATCH_AXIS = "systems"
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across versions: axis_types/AxisType only exist on
-    newer jax; older versions default to Auto semantics anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def _auto_mesh(shape, axes):
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
@@ -46,7 +42,7 @@ def make_host_mesh(model: int = 1):
     distributed smoke tests."""
     n = len(jax.devices())
     data = n // model
-    return compat_make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_solver_mesh(n_devices: int | None = None, axis: str = BATCH_AXIS):
@@ -62,13 +58,53 @@ def make_solver_mesh(n_devices: int | None = None, axis: str = BATCH_AXIS):
     devs = jax.devices()
     n = len(devs) if n_devices is None else int(n_devices)
     if not 1 <= n <= len(devs):
+        platform = devs[0].platform
+        hint = (f"force virtual devices with launch.mesh.ensure_devices({n})"
+                " (or XLA_FLAGS=--xla_force_host_platform_device_count="
+                f"{n}) before jax initializes its backend"
+                if platform == "cpu" else
+                f"this {platform} host has no more chips to shard over")
         raise ValueError(
-            f"make_solver_mesh: asked for {n} devices but "
-            f"{len(devs)} are visible — on CPU, force virtual devices with "
-            "launch.mesh.ensure_virtual_cpu_devices(n) (or XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={n}) before jax "
-            "initializes its backend")
+            f"make_solver_mesh: asked for {n} devices but {len(devs)} "
+            f"{platform} devices are visible — {hint}")
     return jax.sharding.Mesh(np.asarray(devs[:n]), (axis,))
+
+
+def _request_virtual_cpu_devices(n: int) -> None:
+    """Ask for ``n`` virtual CPU devices if no jax backend has started
+    yet (XLA reads the flag once, when the CPU backend initializes)."""
+    try:
+        from jax._src import xla_bridge as _xb
+        initialized = bool(getattr(_xb, "_backends", None))
+    except Exception:                        # private API moved: probe hard
+        initialized = True
+    if not initialized:
+        flags = os.environ.get("XLA_FLAGS", "")
+        if "--xla_force_host_platform_device_count" not in flags:
+            os.environ["XLA_FLAGS"] = (
+                flags + f" --xla_force_host_platform_device_count={n}"
+            ).strip()
+
+
+def ensure_devices(n: int) -> int:
+    """At least ``n`` devices for :func:`make_solver_mesh`: the chips of an
+    accelerator host, else ``n`` virtual CPU devices.
+
+    Run it before anything touches jax devices.  The virtual-device flag
+    it sets shapes only the CPU platform, so on an accelerator host it
+    changes nothing and the real chips are used; only when the default
+    platform is ``cpu`` does :func:`ensure_virtual_cpu_devices` apply.
+    Returns the default platform's device count; raises if it has fewer
+    than ``n``."""
+    n = int(n)
+    _request_virtual_cpu_devices(n)
+    devs = jax.devices()
+    if devs[0].platform == "cpu":
+        return ensure_virtual_cpu_devices(n)
+    if len(devs) < n:
+        raise RuntimeError(f"need {n} devices but this {devs[0].platform} "
+                           f"host has {len(devs)}")
+    return len(devs)
 
 
 def ensure_virtual_cpu_devices(n: int) -> int:
@@ -81,17 +117,7 @@ def ensure_virtual_cpu_devices(n: int) -> int:
     devices than requested (the caller should set ``XLA_FLAGS`` in the
     environment, or run in a subprocess — see tests/test_sharding.py)."""
     n = int(n)
-    try:
-        from jax._src import xla_bridge as _xb
-        initialized = bool(getattr(_xb, "_backends", None))
-    except Exception:                        # private API moved: probe hard
-        initialized = True
-    if not initialized:
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "--xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + f" --xla_force_host_platform_device_count={n}"
-            ).strip()
+    _request_virtual_cpu_devices(n)
     have = len(jax.devices())
     if have < n:
         raise RuntimeError(

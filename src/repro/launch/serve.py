@@ -105,10 +105,15 @@ def print_report(report: dict, file=sys.stdout) -> None:
 
 
 def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
+    if args.devices and args.devices > 1:
+        from repro.launch.mesh import ensure_devices
+        ensure_devices(args.devices)    # before anything touches devices
+
     import jax
     jax.config.update("jax_enable_x64", True)
-
-    args = build_parser().parse_args(argv)
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     report = asyncio.run(_serve_and_drive(args))
     print_report(report)
 

@@ -51,8 +51,8 @@ def test_checkpoint_elastic_reshard(tmp_path):
     ck = Checkpointer(str(tmp_path), async_save=False)
     t = dict(w=jnp.arange(16.0).reshape(4, 4))
     ck.save(1, t)
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = dict(w=NamedSharding(mesh, P("data", None)))
     restored = ck.restore(1, t, shardings=sh)
@@ -217,8 +217,8 @@ import jax, jax.numpy as jnp
 from repro.configs import registry
 from repro.configs.shapes import ShapeCfg
 from repro.launch.dryrun import lower_cell
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((4, 4), ("data", "model"))
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = registry.get("phi3-medium-14b").reduced()
 shape = ShapeCfg("smoke", 64, 8, "train")
 rec = lower_cell(cfg, shape, mesh, "mesh4x4", seq_chunk=32)

@@ -145,3 +145,44 @@ def test_refinement_engaged_parity(fused_case):
     assert np.abs(xf - xh).max() / scale < 1e-12
     assert inff["residual"].max() < 1e-12
     assert infh["residual"].max() < 1e-12
+
+
+@pytest.mark.parametrize("scenario", ["banded", "denseish", "circuit"])
+@pytest.mark.parametrize("upper", [False, True])
+def test_block_solve_schedule_invariants(scenario, upper):
+    """The Pallas block substitution's schedule at routing scale: every
+    row is solved exactly once (as a width-1 node or inside one diagonal
+    block), every off-block dependency reads a row solved at an earlier
+    level, padding points only at the sentinels, and the chunk count —
+    the traced program's size — stays bounded."""
+    from repro.core.structure import block_solve_schedule
+    from tests.helpers import routing_system
+
+    Ac, _, _ = routing_system(scenario)
+    plan = analyze(Ac, HyluOptions(engine="jax", use_pallas=True)).plan
+    n, zero, one = plan.n, plan.total_slots, plan.total_slots + 1
+    chunks = block_solve_schedule(plan, upper=upper)
+    assert 0 < len(chunks) <= 24
+    solved_at = np.full(n + 1, -1)
+    level = 0
+    for ch in chunks:
+        for li in range(ch.dep_rows.shape[0]):
+            dr, dc, ds = ch.dep_rows[li], ch.dep_cols[li], ch.dep_slots[li]
+            real = dr < n
+            assert np.all(ds[~real] == zero) and np.all(dc[~real] == n)
+            assert np.all(solved_at[dc[real]] >= 0)
+            assert np.all(solved_at[dc[real]] < level)
+            rows = np.concatenate([ch.w1_rows[li], ch.blk_rows[li].ravel()])
+            rows = rows[rows < n]
+            assert np.all(solved_at[rows] == -1)
+            assert len(np.unique(rows)) == len(rows)
+            assert set(dr[real]) <= set(rows)
+            solved_at[rows] = level
+            assert np.all(ch.w1_slots[li][ch.w1_rows[li] == n] == one)
+            for br, bs in zip(ch.blk_rows[li], ch.blk_slots[li]):
+                pad = br == n
+                assert np.all(np.diag(bs)[pad] == one)
+                assert np.all(bs[pad][:, ~pad] == zero)
+                assert np.all(bs[~pad][:, pad] == zero)
+            level += 1
+    assert np.all(solved_at[:n] >= 0)

@@ -83,6 +83,6 @@ def test_jax_engine_pallas_path():
     an = analyze(Ac, HyluOptions(force_mode="hybrid"))
     m = _m_values(an, Ac)
     f_ref = ref_engine.factor(an.plan, m)
-    f_jax = jax.jit(make_factor_fn(an.plan, use_pallas=True,
-                                   interpret=True))(jnp.asarray(m.data))
+    f_jax = jax.jit(make_factor_fn(an.plan, use_pallas=True))(
+        jnp.asarray(m.data))
     assert np.abs(np.asarray(f_jax.vals) - f_ref.vals).max() < 1e-10

@@ -18,7 +18,6 @@ the numeric in-node pivot permutation g↦inode_perm[g]:
 from __future__ import annotations
 
 import dataclasses
-import time
 import numpy as np
 
 from .matrix import CSR
@@ -28,6 +27,7 @@ from .kernel_select import select_kernel, KernelChoice
 from .plan import build_plan, FactorPlan
 from .symbolic import Symbolic
 from . import ref_engine
+from .tracing import span
 from .ref_engine import Factors, SolvePlan
 from .options import (HyluOptions, pattern_key, plan_fingerprint,
                       _resolve_mesh, _mesh_cache_key, np_dtype,
@@ -103,49 +103,49 @@ def analyze(a: CSR, opts: HyluOptions | None = None, reuse=None) -> Analysis:
                 "reusing it would produce silently wrong factors — "
                 "run a fresh analyze() for this pattern")
     t: dict[str, float] = {}
-    t0 = time.perf_counter()
-    match = reuse.match if reuse is not None else max_weight_matching(a)
-    t["matching"] = time.perf_counter() - t0
+
+    def phase(name):            # host span hylu.analyze.<name> -> t[name]
+        return span("analyze." + name, into=t, key=name)
+
+    with phase("matching"):
+        match = reuse.match if reuse is not None else max_weight_matching(a)
 
     # permute/scale with index-tracking data so refactor is a pure gather
-    t0 = time.perf_counter()
-    seg = np.repeat(np.arange(a.n), np.diff(a.indptr))
-    scale_entry = match.row_scale[seg] * match.col_scale[a.indices]
-    tracker = CSR(a.n, a.indptr.copy(), a.indices.copy(),
-                  np.arange(a.nnz, dtype=np.float64))
-    q = match.col_of_row.copy()
-    b2_track = tracker.permute(np.arange(a.n), q)
+    with phase("ordering"):
+        seg = np.repeat(np.arange(a.n), np.diff(a.indptr))
+        scale_entry = match.row_scale[seg] * match.col_scale[a.indices]
+        tracker = CSR(a.n, a.indptr.copy(), a.indices.copy(),
+                      np.arange(a.nnz, dtype=np.float64))
+        q = match.col_of_row.copy()
+        b2_track = tracker.permute(np.arange(a.n), q)
 
-    pat2 = CSR(a.n, b2_track.indptr, b2_track.indices,
-               np.ones(a.nnz)).sym_pattern()
-    if reuse is not None:
-        p, ord_name = reuse.p, reuse.ordering_name
-    else:
-        p, ord_name = select_ordering(pat2, candidates=opts.orderings)
-    t["ordering"] = time.perf_counter() - t0
+        pat2 = CSR(a.n, b2_track.indptr, b2_track.indices,
+                   np.ones(a.nnz)).sym_pattern()
+        if reuse is not None:
+            p, ord_name = reuse.p, reuse.ordering_name
+        else:
+            p, ord_name = select_ordering(pat2, candidates=opts.orderings)
 
-    t0 = time.perf_counter()
-    m_track = b2_track.permute(p, p)
-    src_map = m_track.data.astype(np.int64)
-    scale_map = scale_entry[src_map]
-    pat_m = pat2.permute(p, p)
-    choice, sym = select_kernel(pat_m, force_mode=opts.force_mode,
-                                relax=opts.relax, max_super=opts.max_super)
-    t["symbolic"] = time.perf_counter() - t0
+    with phase("symbolic"):
+        m_track = b2_track.permute(p, p)
+        src_map = m_track.data.astype(np.int64)
+        scale_map = scale_entry[src_map]
+        pat_m = pat2.permute(p, p)
+        choice, sym = select_kernel(pat_m, force_mode=opts.force_mode,
+                                    relax=opts.relax,
+                                    max_super=opts.max_super)
 
     if opts.amalg_fill_tol > 0:
         from .structure import amalgamate_supernodes
-        t0 = time.perf_counter()
-        sym, amalg_stats = amalgamate_supernodes(
-            sym, fill_tol=opts.amalg_fill_tol, max_super=opts.max_super)
-        choice.stats["amalg"] = amalg_stats
-        t["amalgamate"] = time.perf_counter() - t0
+        with phase("amalgamate"):
+            sym, amalg_stats = amalgamate_supernodes(
+                sym, fill_tol=opts.amalg_fill_tol, max_super=opts.max_super)
+            choice.stats["amalg"] = amalg_stats
 
-    t0 = time.perf_counter()
-    m = CSR(a.n, m_track.indptr, m_track.indices, np.ones(a.nnz))
-    plan = build_plan(pat_m, m, sym, mode=choice.mode,
-                      bulk_min_width=opts.bulk_min_width)
-    t["plan"] = time.perf_counter() - t0
+    with phase("plan"):
+        m = CSR(a.n, m_track.indptr, m_track.indices, np.ones(a.nnz))
+        plan = build_plan(pat_m, m, sym, mode=choice.mode,
+                          bulk_min_width=opts.bulk_min_width)
     t["total"] = sum(t.values())
 
     return Analysis(n=a.n, opts=opts, match=match, q=q, p=p,
@@ -216,10 +216,9 @@ def _factor_jax(an: Analysis, a: CSR) -> FactorState:
 
     eng = jax_repeated_engine(an)
     t = {}
-    t0 = time.perf_counter()
-    jf = eng.refactor(jnp.asarray(a.data))
-    jax.block_until_ready(jf.vals)
-    t["factor"] = time.perf_counter() - t0
+    with span("factor", into=t):
+        jf = eng.refactor(jnp.asarray(a.data))
+        jax.block_until_ready(jf.vals)
     return FactorState(analysis=an, factors=None, solve_plan=None, a=a,
                        timings=t, engine="jax", jax_factors=jf)
 
@@ -241,13 +240,12 @@ def factor(an: Analysis, a: CSR, engine=None) -> FactorState:
         raise ValueError(f"unknown engine {engine!r}: expected 'ref', 'jax', "
                          "or an engine module with a factor() function")
     t = {}
-    t0 = time.perf_counter()
-    m = _m_values(an, a)
-    f = mod.factor(an.plan, m, perturb_eps=an.opts.perturb_eps)
-    t["factor"] = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    sp = ref_engine.build_solve_plan(f, bulk_min_width=an.opts.bulk_min_width)
-    t["solve_plan"] = time.perf_counter() - t0
+    with span("factor", into=t):
+        f = mod.factor(an.plan, _m_values(an, a),
+                       perturb_eps=an.opts.perturb_eps)
+    with span("solve_plan", into=t):
+        sp = ref_engine.build_solve_plan(
+            f, bulk_min_width=an.opts.bulk_min_width)
     return FactorState(analysis=an, factors=f, solve_plan=sp, a=a, timings=t)
 
 
@@ -259,13 +257,12 @@ def refactor(st: FactorState, a_new: CSR) -> FactorState:
     if st.engine == "jax":
         return _factor_jax(an, a_new)
     t = {}
-    t0 = time.perf_counter()
-    m = _m_values(an, a_new)
-    f = ref_engine.factor(an.plan, m, perturb_eps=an.opts.perturb_eps)
-    t["factor"] = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    sp = ref_engine.build_solve_plan(f, bulk_min_width=an.opts.bulk_min_width)
-    t["solve_plan"] = time.perf_counter() - t0
+    with span("factor", into=t):
+        f = ref_engine.factor(an.plan, _m_values(an, a_new),
+                              perturb_eps=an.opts.perturb_eps)
+    with span("solve_plan", into=t):
+        sp = ref_engine.build_solve_plan(
+            f, bulk_min_width=an.opts.bulk_min_width)
     return FactorState(analysis=an, factors=f, solve_plan=sp, a=a_new, timings=t)
 
 
@@ -274,55 +271,56 @@ def solve(st: FactorState, b: np.ndarray, refine: bool | None = None) -> tuple:
     perturbation occurred, per paper §2.3). Returns (x, info)."""
     an = st.analysis
     opts = an.opts
-    t0 = time.perf_counter()
+    t = {}
+    with span("solve", into=t):
+        if st.engine == "jax":
+            import jax.numpy as jnp
 
-    if st.engine == "jax":
-        import jax.numpy as jnp
+            eng = jax_repeated_engine(an)
+            jf = st.jax_factors
+            n_perturb = int(jf.n_perturb)
+            rtol = resolve_refine_tol(opts, eng.refine_dtype)
 
-        eng = jax_repeated_engine(an)
-        jf = st.jax_factors
-        n_perturb = int(jf.n_perturb)
-        rtol = resolve_refine_tol(opts, eng.refine_dtype)
+            def lu_apply(rhs: np.ndarray) -> np.ndarray:
+                return np.asarray(eng.apply(jf.vals, jf.inode_perm,
+                                            jnp.asarray(rhs)))
+        else:
+            f = st.factors
+            n_perturb = f.n_perturb
+            rtol = resolve_refine_tol(opts, "float64")
 
-        def lu_apply(rhs: np.ndarray) -> np.ndarray:
-            return np.asarray(eng.apply(jf.vals, jf.inode_perm,
-                                        jnp.asarray(rhs)))
-    else:
-        f = st.factors
-        n_perturb = f.n_perturb
-        rtol = resolve_refine_tol(opts, "float64")
+            def lu_apply(rhs: np.ndarray) -> np.ndarray:
+                c = (an.match.row_scale * rhs)[an.p][f.inode_perm]
+                w = ref_engine.solve_lu(st.solve_plan, c)
+                z = np.empty_like(w); z[an.p] = w
+                y = np.empty_like(z); y[an.q] = z
+                return an.match.col_scale * y
 
-        def lu_apply(rhs: np.ndarray) -> np.ndarray:
-            c = (an.match.row_scale * rhs)[an.p][f.inode_perm]
-            w = ref_engine.solve_lu(st.solve_plan, c)
-            z = np.empty_like(w); z[an.p] = w
-            y = np.empty_like(z); y[an.q] = z
-            return an.match.col_scale * y
-
-    # accumulate x and the residual in float64 on the host regardless of the
-    # engine's factor dtype (the batched path does the same in refine_dtype)
-    x = np.asarray(lu_apply(b), dtype=np.float64)
-    n_ref = 0
-    bnorm = float(np.abs(b).sum()) or 1.0
-    resid = float(np.abs(b - st.a.matvec(x)).sum()) / bnorm
-    # auto-refine when pivot perturbation occurred (paper §2.3) or the
-    # residual is above the target
-    do_refine = refine if refine is not None else (
-        n_perturb > 0 or resid > rtol)
-    if do_refine:
-        for _ in range(opts.refine_max_iter):
-            if resid <= rtol:
-                break
-            r = b - st.a.matvec(x)
-            x2 = x + lu_apply(r)
-            resid2 = float(np.abs(b - st.a.matvec(x2)).sum()) / bnorm
-            n_ref += 1
-            if resid2 >= resid:
-                break
-            x, resid = x2, resid2
+        # accumulate x and the residual in float64 on the host regardless
+        # of the engine's factor dtype (the batched path does the same in
+        # refine_dtype)
+        x = np.asarray(lu_apply(b), dtype=np.float64)
+        n_ref = 0
+        bnorm = float(np.abs(b).sum()) or 1.0
+        resid = float(np.abs(b - st.a.matvec(x)).sum()) / bnorm
+        # auto-refine when pivot perturbation occurred (paper §2.3) or the
+        # residual is above the target
+        do_refine = refine if refine is not None else (
+            n_perturb > 0 or resid > rtol)
+        if do_refine:
+            for _ in range(opts.refine_max_iter):
+                if resid <= rtol:
+                    break
+                r = b - st.a.matvec(x)
+                x2 = x + lu_apply(r)
+                resid2 = float(np.abs(b - st.a.matvec(x2)).sum()) / bnorm
+                n_ref += 1
+                if resid2 >= resid:
+                    break
+                x, resid = x2, resid2
     info = dict(residual=resid, n_refine=n_ref, n_perturb=n_perturb,
                 refine_failed=bool(do_refine and resid > rtol),
-                solve_time=time.perf_counter() - t0)
+                solve_time=t["solve"])
     return x, info
 
 

@@ -12,12 +12,12 @@ heterogeneous traffic onto these entry points, one group per pattern.
 from __future__ import annotations
 
 import dataclasses
-import time
 import numpy as np
 
 from .matrix import CSR
 from .analysis import Analysis, analyze, jax_repeated_engine
 from .options import HyluOptions, resolve_refine_tol
+from .tracing import span
 
 
 @dataclasses.dataclass
@@ -100,6 +100,7 @@ def _pad_k(eng, k: int) -> int:
     return -(-k // eng.n_shards) * eng.n_shards
 
 
+@span("stage")
 def _stage_values(eng, values_batch):
     """Stage a (K, nnz) value set on device for the batched engine.
 
@@ -144,6 +145,7 @@ def _stage_values(eng, values_batch):
     return v, host, k
 
 
+@span("stage")
 def _stage_rhs(eng, b_batch, k: int, copy: bool = False):
     """Stage right-hand sides (K, n) / (n,) broadcast / (K, n, m) on device:
     same device-buffer honoring, zero-padding of K to the mesh multiple
@@ -198,11 +200,10 @@ def factor_batched(an: Analysis, a_pattern, values_batch) -> BatchedFactorState:
 
     eng = jax_repeated_engine(an)
     t = {}
-    t0 = time.perf_counter()
-    values_dev, values_host, k = _stage_values(eng, values_batch)
-    jf = eng.refactor_batched(values_dev)
-    jax.block_until_ready(jf.vals)
-    t["factor_batched"] = time.perf_counter() - t0
+    with span("factor_batched", into=t):
+        values_dev, values_host, k = _stage_values(eng, values_batch)
+        jf = eng.refactor_batched(values_dev)
+        jax.block_until_ready(jf.vals)
     return BatchedFactorState(
         analysis=an, a_pattern=_pattern_of(a_pattern),
         values_dev=values_dev, vals=jf.vals, inode_perm=jf.inode_perm,
@@ -253,53 +254,55 @@ def solve_batched(bst: BatchedFactorState, b_batch: np.ndarray,
         raise RuntimeError(
             "this BatchedFactorState was consumed by a donating solve — "
             "refactor (factor_batched) before solving again")
-    t0 = time.perf_counter()
-    max_iter = 0 if refine is False else opts.refine_max_iter
-    # the escape hatch needs the original fp64 values, so it only arms on a
-    # reduced-factor engine whose staging (= refine) dtype is float64
-    fallback_armed = (
-        max_iter > 0 and bool(opts.fp64_fallback)
-        and np.dtype(eng.factor_dtype) != np.float64
-        and np.dtype(eng.values_dtype) == np.float64)
-    if donate and bst._values_host is None:
-        _ = bst.values_batch    # materialize the host oracle before the
-        #                         device buffer is donated away
-    b_dev = _stage_rhs(eng, b_batch, bst.k)
-    # a donated RHS buffer dies with the call — snapshot it while the
-    # fallback might still need to re-solve a failed subset
-    b_src = np.asarray(b_dev) if (donate and fallback_armed) else b_dev
-    solver = eng.refined_batched_solver(*bst.a_pattern, donate=donate)
-    x, resid, n_iter, n_ref_sys, stalled, failed = solver(
-        bst.vals, bst.inode_perm, bst.values_dev,
-        b_dev, max_iter, resolve_refine_tol(opts, eng.refine_dtype))
-    if donate:
-        bst.consumed = True
-        bst.values_dev = None
-    k = bst.k
-    x = np.asarray(x)[:k]
-    failed_h = np.asarray(failed)[:k]
-    info = dict(residual=np.asarray(resid)[:k], n_refine=int(n_iter),
-                n_refine_per_system=np.asarray(n_ref_sys)[:k],
-                n_perturb=bst.n_perturb,
-                refine_stalled=np.asarray(stalled)[:k],
-                refine_failed=failed_h,
-                factor_dtype=np.dtype(eng.factor_dtype).name,
-                fallback_mask=np.zeros(k, bool), n_fp64_fallback=0,
-                solve_time=time.perf_counter() - t0,
-                escalation=(["refine"] if max_iter > 0 else []))
-    if max_iter > 0:
-        # a NaN/Inf residual or solution must count as failed: the device
-        # mask is `resid > tol`, and NaN compares False — without this a
-        # numerically singular system's NaN solution would sail through
-        # flagged converged (silent garbage instead of an honest failure)
-        failed_h = info["refine_failed"] = _nonfinite_failed(x, info)
-    if fallback_armed and failed_h.any():
-        x = _fp64_redo(bst, b_src, x, info)
-        info["escalation"].append("fp64_fallback")
-        # the redo's own masks come from the same `> tol` comparison —
-        # guard them too in case the fp64 re-solve is still non-finite
-        info["refine_failed"] = _nonfinite_failed(x, info)
-        info["solve_time"] = time.perf_counter() - t0
+    t = {}
+    with span("solve_batched", into=t):
+        max_iter = 0 if refine is False else opts.refine_max_iter
+        # the escape hatch needs the original fp64 values, so it only arms
+        # on a reduced-factor engine whose staging (= refine) dtype is
+        # float64
+        fallback_armed = (
+            max_iter > 0 and bool(opts.fp64_fallback)
+            and np.dtype(eng.factor_dtype) != np.float64
+            and np.dtype(eng.values_dtype) == np.float64)
+        if donate and bst._values_host is None:
+            _ = bst.values_batch    # materialize the host oracle before
+            #                         the device buffer is donated away
+        b_dev = _stage_rhs(eng, b_batch, bst.k)
+        # a donated RHS buffer dies with the call — snapshot it while the
+        # fallback might still need to re-solve a failed subset
+        b_src = np.asarray(b_dev) if (donate and fallback_armed) else b_dev
+        solver = eng.refined_batched_solver(*bst.a_pattern, donate=donate)
+        x, resid, n_iter, n_ref_sys, stalled, failed = solver(
+            bst.vals, bst.inode_perm, bst.values_dev,
+            b_dev, max_iter, resolve_refine_tol(opts, eng.refine_dtype))
+        if donate:
+            bst.consumed = True
+            bst.values_dev = None
+        k = bst.k
+        x = np.asarray(x)[:k]
+        failed_h = np.asarray(failed)[:k]
+        info = dict(residual=np.asarray(resid)[:k], n_refine=int(n_iter),
+                    n_refine_per_system=np.asarray(n_ref_sys)[:k],
+                    n_perturb=bst.n_perturb,
+                    refine_stalled=np.asarray(stalled)[:k],
+                    refine_failed=failed_h,
+                    factor_dtype=np.dtype(eng.factor_dtype).name,
+                    fallback_mask=np.zeros(k, bool), n_fp64_fallback=0,
+                    escalation=(["refine"] if max_iter > 0 else []))
+        if max_iter > 0:
+            # a NaN/Inf residual or solution must count as failed: the
+            # device mask is `resid > tol`, and NaN compares False —
+            # without this a numerically singular system's NaN solution
+            # would sail through flagged converged (silent garbage instead
+            # of an honest failure)
+            failed_h = info["refine_failed"] = _nonfinite_failed(x, info)
+        if fallback_armed and failed_h.any():
+            x = _fp64_redo(bst, b_src, x, info)
+            info["escalation"].append("fp64_fallback")
+            # the redo's own masks come from the same `> tol` comparison —
+            # guard them too in case the fp64 re-solve is still non-finite
+            info["refine_failed"] = _nonfinite_failed(x, info)
+    info["solve_time"] = t["solve_batched"]
     return x, info
 
 
@@ -325,32 +328,32 @@ def _fp64_redo(bst: BatchedFactorState, b_src, x: np.ndarray,
     discarded for these systems."""
     an = bst.analysis
     opts = an.opts
-    t0 = time.perf_counter()
-    failed_h = info["refine_failed"]
-    sys_mask = failed_h if failed_h.ndim == 1 else failed_h.any(axis=1)
-    idx = np.nonzero(sys_mask)[0]
-    eng64 = jax_repeated_engine(an, dtype=np.float64,
-                                refine_dtype=np.float64)
-    v_sub = np.ascontiguousarray(
-        np.asarray(bst.values_batch, dtype=np.float64)[idx])
-    b_sub = np.ascontiguousarray(np.asarray(b_src)[idx])
-    v_dev, _, f = _stage_values(eng64, v_sub)
-    jf = eng64.refactor_batched(v_dev)
-    b_dev = _stage_rhs(eng64, b_sub, f)
-    solver = eng64.refined_batched_solver(*bst.a_pattern)
-    x64, resid64, _, n_ref64, st64, fl64 = solver(
-        jf.vals, jf.inode_perm, v_dev, b_dev, opts.refine_max_iter,
-        resolve_refine_tol(opts, "float64"))
-    x = np.array(x)                       # jax views are read-only; splice
-    x[idx] = np.asarray(x64)[:f].astype(x.dtype)
-    for key, new in (("residual", resid64), ("n_refine_per_system", n_ref64),
-                     ("refine_stalled", st64), ("refine_failed", fl64)):
-        merged = np.array(info[key])
-        merged[idx] = np.asarray(new)[:f]
-        info[key] = merged
-    info["fallback_mask"] = sys_mask
-    info["n_fp64_fallback"] = int(len(idx))
-    info["fallback_time"] = time.perf_counter() - t0
+    with span("fp64_fallback", into=info, key="fallback_time"):
+        failed_h = info["refine_failed"]
+        sys_mask = failed_h if failed_h.ndim == 1 else failed_h.any(axis=1)
+        idx = np.nonzero(sys_mask)[0]
+        eng64 = jax_repeated_engine(an, dtype=np.float64,
+                                    refine_dtype=np.float64)
+        v_sub = np.ascontiguousarray(
+            np.asarray(bst.values_batch, dtype=np.float64)[idx])
+        b_sub = np.ascontiguousarray(np.asarray(b_src)[idx])
+        v_dev, _, f = _stage_values(eng64, v_sub)
+        jf = eng64.refactor_batched(v_dev)
+        b_dev = _stage_rhs(eng64, b_sub, f)
+        solver = eng64.refined_batched_solver(*bst.a_pattern)
+        x64, resid64, _, n_ref64, st64, fl64 = solver(
+            jf.vals, jf.inode_perm, v_dev, b_dev, opts.refine_max_iter,
+            resolve_refine_tol(opts, "float64"))
+        x = np.array(x)                   # jax views are read-only; splice
+        x[idx] = np.asarray(x64)[:f].astype(x.dtype)
+        for key, new in (("residual", resid64),
+                         ("n_refine_per_system", n_ref64),
+                         ("refine_stalled", st64), ("refine_failed", fl64)):
+            merged = np.array(info[key])
+            merged[idx] = np.asarray(new)[:f]
+            info[key] = merged
+        info["fallback_mask"] = sys_mask
+        info["n_fp64_fallback"] = int(len(idx))
     return x
 
 
@@ -366,49 +369,52 @@ def _solve_batched_hostloop(bst: BatchedFactorState, b_batch: np.ndarray,
     an = bst.analysis
     opts = an.opts
     eng = jax_repeated_engine(an)
-    t0 = time.perf_counter()
-    # stage/accumulate in the engine's refine dtype, like the fused path
-    # (the substitution itself runs in the factor dtype inside apply_batched)
-    rdt = np.dtype(eng.refine_dtype)
-    tol = resolve_refine_tol(opts, eng.refine_dtype)
-    b_batch = np.asarray(b_batch, dtype=rdt)
-    if b_batch.ndim == 1:
-        b_batch = np.broadcast_to(b_batch, (bst.k, b_batch.shape[0]))
+    t = {}
+    with span("solve_batched", into=t):
+        # stage/accumulate in the engine's refine dtype, like the fused
+        # path (the substitution itself runs in the factor dtype inside
+        # apply_batched)
+        rdt = np.dtype(eng.refine_dtype)
+        tol = resolve_refine_tol(opts, eng.refine_dtype)
+        b_batch = np.asarray(b_batch, dtype=rdt)
+        if b_batch.ndim == 1:
+            b_batch = np.broadcast_to(b_batch, (bst.k, b_batch.shape[0]))
 
-    # the oracle path always runs unsharded at the true batch size: slice
-    # any mesh padding off the (possibly sharded) device buffers
-    vals_k, inode_k = bst.vals[:bst.k], bst.inode_perm[:bst.k]
+        # the oracle path always runs unsharded at the true batch size:
+        # slice any mesh padding off the (possibly sharded) device buffers
+        vals_k, inode_k = bst.vals[:bst.k], bst.inode_perm[:bst.k]
 
-    def residuals(x):
-        r = b_batch - _batched_matvec(bst.a_pattern, bst.values_batch, x)
-        return r, np.abs(r).sum(axis=1) / bnorm
+        def residuals(x):
+            r = b_batch - _batched_matvec(bst.a_pattern, bst.values_batch,
+                                          x)
+            return r, np.abs(r).sum(axis=1) / bnorm
 
-    bnorm = np.abs(b_batch).sum(axis=1)          # (K,) or (K, m)
-    bnorm = np.where(bnorm == 0.0, 1.0, bnorm)
-    x = np.asarray(eng.apply_batched(vals_k, inode_k,
-                                     jnp.asarray(b_batch))).astype(rdt)
-    r, resid = residuals(x)
-    n_ref = 0
-    alive = np.ones(resid.shape, bool)
-    max_iter = 0 if refine is False else opts.refine_max_iter
-    for _ in range(max_iter):
-        need = alive & (resid > tol)
-        if not need.any():
-            break
-        x2 = x + np.asarray(eng.apply_batched(vals_k, inode_k,
-                                              jnp.asarray(r))).astype(rdt)
-        r2, resid2 = residuals(x2)
-        n_ref += 1
-        improved = resid2 < resid
-        upd = need & improved                     # mirror the fused masking
-        x = np.where(upd[:, None], x2, x)
-        r = np.where(upd[:, None], r2, r)
-        resid = np.where(upd, resid2, resid)
-        alive = alive & (improved | ~need)
+        bnorm = np.abs(b_batch).sum(axis=1)          # (K,) or (K, m)
+        bnorm = np.where(bnorm == 0.0, 1.0, bnorm)
+        x = np.asarray(eng.apply_batched(vals_k, inode_k,
+                                         jnp.asarray(b_batch))).astype(rdt)
+        r, resid = residuals(x)
+        n_ref = 0
+        alive = np.ones(resid.shape, bool)
+        max_iter = 0 if refine is False else opts.refine_max_iter
+        for _ in range(max_iter):
+            need = alive & (resid > tol)
+            if not need.any():
+                break
+            x2 = x + np.asarray(eng.apply_batched(
+                vals_k, inode_k, jnp.asarray(r))).astype(rdt)
+            r2, resid2 = residuals(x2)
+            n_ref += 1
+            improved = resid2 < resid
+            upd = need & improved                 # mirror the fused masking
+            x = np.where(upd[:, None], x2, x)
+            r = np.where(upd[:, None], r2, r)
+            resid = np.where(upd, resid2, resid)
+            alive = alive & (improved | ~need)
     failed = (resid > tol) & (max_iter > 0)
     info = dict(residual=resid, n_refine=n_ref, n_perturb=bst.n_perturb,
                 refine_failed=failed, refine_stalled=failed & ~alive,
-                solve_time=time.perf_counter() - t0)
+                solve_time=t["solve_batched"])
     return x, info
 
 
@@ -528,38 +534,39 @@ def _solve_sequence_pipelined(a_pattern, values_steps, b_steps,
     max_iter = opts.refine_max_iter
     tol = resolve_refine_tol(opts, eng.refine_dtype)
 
-    t_all = time.perf_counter()
-    # stage step 0 (the analysis already synced the host, so this is cheap);
-    # copy=donate: a donated staging buffer must never BE the caller's (or
-    # a shared across-steps) committed array — step t+1 restages it
-    v_dev, _, k = _stage_values(eng, steps_v[0])
-    b_dev = _stage_rhs(eng, b_of(0), k, copy=donate)
-    outs, n_pert = [], []
-    prev = None
-    for t in range(n_steps):
-        if donate and prev is not None:
-            jf = eng.refactor_batched_reuse(prev.vals, prev.inode_perm,
-                                            v_dev)
-        else:
-            jf = eng.refactor_batched(v_dev)
-        x, resid, n_iter, n_ref, stalled, failed = solver(
-            jf.vals, jf.inode_perm, v_dev, b_dev, max_iter, tol)
-        # stage step t+1 while the device chews on step t — this H2D copy
-        # is the one the double-buffering hides
-        if t + 1 < n_steps:
-            v_dev, _, k2 = _stage_values(eng, steps_v[t + 1])
-            if k2 != k:
-                raise ValueError(f"step {t + 1} has batch size {k2}, "
-                                 f"step 0 had {k}")
-            b_dev = _stage_rhs(eng, b_of(t + 1), k, copy=donate)
-        outs.append((x, resid, n_iter, n_ref, stalled, failed))
-        n_pert.append(jf.n_perturb)
-        # hold the factors only to donate them: otherwise step t's buffers
-        # would stay live through step t+1's refactor
-        prev = jf if donate else None
-        del jf
-    jax.block_until_ready(outs[-1][0])           # the single sync point
-    t_all = time.perf_counter() - t_all
+    tm = {}
+    with span("pipeline", into=tm):
+        # stage step 0 (the analysis already synced the host, so this is
+        # cheap); copy=donate: a donated staging buffer must never BE the
+        # caller's (or a shared across-steps) committed array — step t+1
+        # restages it
+        v_dev, _, k = _stage_values(eng, steps_v[0])
+        b_dev = _stage_rhs(eng, b_of(0), k, copy=donate)
+        outs, n_pert = [], []
+        prev = None
+        for t in range(n_steps):
+            if donate and prev is not None:
+                jf = eng.refactor_batched_reuse(prev.vals, prev.inode_perm,
+                                                v_dev)
+            else:
+                jf = eng.refactor_batched(v_dev)
+            x, resid, n_iter, n_ref, stalled, failed = solver(
+                jf.vals, jf.inode_perm, v_dev, b_dev, max_iter, tol)
+            # stage step t+1 while the device chews on step t — this H2D
+            # copy is the one the double-buffering hides
+            if t + 1 < n_steps:
+                v_dev, _, k2 = _stage_values(eng, steps_v[t + 1])
+                if k2 != k:
+                    raise ValueError(f"step {t + 1} has batch size {k2}, "
+                                     f"step 0 had {k}")
+                b_dev = _stage_rhs(eng, b_of(t + 1), k, copy=donate)
+            outs.append((x, resid, n_iter, n_ref, stalled, failed))
+            n_pert.append(jf.n_perturb)
+            # hold the factors only to donate them: otherwise step t's
+            # buffers would stay live through step t+1's refactor
+            prev = jf if donate else None
+            del jf
+        jax.block_until_ready(outs[-1][0])           # the single sync point
 
     x = np.stack([np.asarray(o[0])[:k] for o in outs])
     resid = np.stack([np.asarray(o[1])[:k] for o in outs])
@@ -575,8 +582,9 @@ def _solve_sequence_pipelined(a_pattern, values_steps, b_steps,
                     [np.asarray(o[4])[:k] for o in outs]),
                 refine_failed=np.stack(
                     [np.asarray(o[5])[:k] for o in outs]),
-                solve_time=t_all,
-                timings={"preprocess": an.timings, "pipeline": t_all},
+                solve_time=tm["pipeline"],
+                timings={"preprocess": an.timings,
+                         "pipeline": tm["pipeline"]},
                 mode=an.choice.mode, ordering=an.ordering_name,
                 engine="jax-batched", k=k, steps=n_steps,
                 donate=donate)

@@ -35,6 +35,7 @@ import numpy as np
 from repro.kernels import interpret_mode
 
 from .plan import FactorPlan
+from .tracing import scope
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -198,69 +199,75 @@ def _make_factor_fn_bucketed(plan: FactorPlan, perturb_eps, dtype,
     offs = plan.panel_offset
 
     def factor_fn(b_data: jax.Array) -> JaxFactors:
-        b_data = b_data.astype(dtype)
-        amax = jnp.max(jnp.abs(b_data))
-        eps_p = perturb_eps * amax
-        vals = jnp.zeros((sched.n_ext,), dtype=dtype)
-        vals = vals.at[plan.a_scatter].set(b_data)
-        # identity-pivot sentinel: a huge value rather than 1.0, so padded
-        # diagonals can never test as "small" even under absurd
-        # perturb_eps settings (|1e30| < eps_p is false for any sane eps;
-        # padded TRSM/divide still yields exact zeros: 0 / 1e30 == 0)
-        vals = vals.at[sched.one_slot].set(jnp.asarray(1e30, dtype))
-        inode = jnp.arange(plan.n + 1, dtype=jnp.int32)
-        nper = jnp.int32(0)
+        with scope("factor.stage"):
+            b_data = b_data.astype(dtype)
+            amax = jnp.max(jnp.abs(b_data))
+            eps_p = perturb_eps * amax
+            vals = jnp.zeros((sched.n_ext,), dtype=dtype)
+            vals = vals.at[plan.a_scatter].set(b_data)
+            # identity-pivot sentinel: a huge value rather than 1.0, so
+            # padded diagonals can never test as "small" even under absurd
+            # perturb_eps settings (|1e30| < eps_p is false for any sane
+            # eps; padded TRSM/divide still yields exact zeros:
+            # 0 / 1e30 == 0)
+            vals = vals.at[sched.one_slot].set(jnp.asarray(1e30, dtype))
+            inode = jnp.arange(plan.n + 1, dtype=jnp.int32)
+            nper = jnp.int32(0)
 
         for step in sched.steps:
             # ---- internal factorization of this level's nodes ------------
-            if step.diag is not None:           # width-1: perturb diagonals
-                dsl = jnp.asarray(step.diag.slots)
-                d = vals[dsl]
-                small = jnp.abs(d) < eps_p
-                d = jnp.where(small, jnp.where(d >= 0, eps_p, -eps_p), d)
-                vals = vals.at[dsl].set(d)
-                nper = nper + jnp.sum(small).astype(jnp.int32)
-            for pb in step.panels:              # wider: bucketed dense LU
-                P = vals[jnp.asarray(pb.gather)]
-                P, perm, npb = _panel_lu_bucketed(
-                    P, pb.wu, eps_p, use_pallas=use_pallas)
-                vals = vals.at[jnp.asarray(pb.scatter)].set(P)
-                nper = nper + jnp.sum(npb).astype(jnp.int32)
-                rows = jnp.asarray(pb.rows)
-                seg = inode[rows]
-                inode = inode.at[rows].set(
-                    jnp.take_along_axis(seg, perm, axis=1))
-            for t in step.seq:                  # narrow level: per-node LU
-                nd = nodes[int(t)]
-                off = int(offs[nd.nid])
-                panel = jax.lax.dynamic_slice(
-                    vals, (off,), (nd.nr * nd.width,)).reshape(nd.nr,
-                                                               nd.width)
-                vals, inode, nper = _node_lu_writeback(
-                    vals, inode, nper, nd, panel, off, eps_p, use_pallas)
+            with scope("factor.panel"):
+                if step.diag is not None:       # width-1: perturb diagonals
+                    dsl = jnp.asarray(step.diag.slots)
+                    d = vals[dsl]
+                    small = jnp.abs(d) < eps_p
+                    d = jnp.where(small, jnp.where(d >= 0, eps_p, -eps_p), d)
+                    vals = vals.at[dsl].set(d)
+                    nper = nper + jnp.sum(small).astype(jnp.int32)
+                for pb in step.panels:          # wider: bucketed dense LU
+                    P = vals[jnp.asarray(pb.gather)]
+                    P, perm, npb = _panel_lu_bucketed(
+                        P, pb.wu, eps_p, use_pallas=use_pallas)
+                    vals = vals.at[jnp.asarray(pb.scatter)].set(P)
+                    nper = nper + jnp.sum(npb).astype(jnp.int32)
+                    rows = jnp.asarray(pb.rows)
+                    seg = inode[rows]
+                    inode = inode.at[rows].set(
+                        jnp.take_along_axis(seg, perm, axis=1))
+                for t in step.seq:              # narrow level: per-node LU
+                    nd = nodes[int(t)]
+                    off = int(offs[nd.nid])
+                    panel = jax.lax.dynamic_slice(
+                        vals, (off,), (nd.nr * nd.width,)).reshape(nd.nr,
+                                                                   nd.width)
+                    vals, inode, nper = _node_lu_writeback(
+                        vals, inode, nper, nd, panel, off, eps_p, use_pallas)
             # ---- eager application of this level's outgoing edges --------
-            for eb in step.edges:
-                S = vals[jnp.asarray(eb.src_idx)]     # (E, k, k+m)
-                U, Us = S[:, :, :eb.k], S[:, :, eb.k:]
-                X = vals[jnp.asarray(eb.x_idx)]       # (E, nr, k)
-                if eb.k == 1:                         # row-row / sup-row
-                    lts = X / U[:, 0, 0][:, None, None]
-                    delta = lts * Us                  # (E, nr, 1)·(E, 1, m)
-                elif use_pallas:                      # sup-sup on Pallas
-                    from repro.kernels.supsup import ops as supsup_ops
-                    from repro.kernels.trisolve import ops as trisolve_ops
-                    lts = trisolve_ops.trsm_batched(U, X)
-                    delta = supsup_ops.gemm_batched(lts, Us)
-                else:                                 # sup-sup via XLA
-                    lts = jax.lax.linalg.triangular_solve(
-                        U, X, left_side=False, lower=False)
-                    delta = jnp.matmul(lts, Us, precision=_HIGHEST)
-                # one combined scatter: multiplier write-back expressed as
-                # an add of (lts - X), trailing update as -delta
-                ne = lts.shape[0]
-                w_vals = jnp.concatenate([(lts - X).reshape(ne, -1),
-                                          (-delta).reshape(ne, -1)], axis=1)
-                vals = vals.at[jnp.asarray(eb.write_idx)].add(w_vals)
+            with scope("factor.edge"):
+                for eb in step.edges:
+                    S = vals[jnp.asarray(eb.src_idx)]     # (E, k, k+m)
+                    U, Us = S[:, :, :eb.k], S[:, :, eb.k:]
+                    X = vals[jnp.asarray(eb.x_idx)]       # (E, nr, k)
+                    if eb.k == 1:                         # row-row / sup-row
+                        lts = X / U[:, 0, 0][:, None, None]
+                        delta = lts * Us              # (E, nr, 1)·(E, 1, m)
+                    elif use_pallas:                  # sup-sup on Pallas
+                        from repro.kernels.supsup import ops as supsup_ops
+                        from repro.kernels.trisolve import ops as \
+                            trisolve_ops
+                        lts = trisolve_ops.trsm_batched(U, X)
+                        delta = supsup_ops.gemm_batched(lts, Us)
+                    else:                             # sup-sup via XLA
+                        lts = jax.lax.linalg.triangular_solve(
+                            U, X, left_side=False, lower=False)
+                        delta = jnp.matmul(lts, Us, precision=_HIGHEST)
+                    # one combined scatter: multiplier write-back expressed
+                    # as an add of (lts - X), trailing update as -delta
+                    ne = lts.shape[0]
+                    w_vals = jnp.concatenate([(lts - X).reshape(ne, -1),
+                                              (-delta).reshape(ne, -1)],
+                                             axis=1)
+                    vals = vals.at[jnp.asarray(eb.write_idx)].add(w_vals)
 
         # ---- scanned width-1 suffix: one traced body per chunk -----------
         def scan_body(carry, xs):
@@ -279,14 +286,16 @@ def _make_factor_fn_bucketed(plan: FactorPlan, perturb_eps, dtype,
             vals = vals.at[w_i].add(upd)
             return (vals, nper), None
 
-        for ch in sched.scan_chunks:
-            (vals, nper), _ = jax.lax.scan(
-                scan_body, (vals, nper),
-                (jnp.asarray(ch.dsl), jnp.asarray(ch.x_idx),
-                 jnp.asarray(ch.src_idx), jnp.asarray(ch.write_idx)))
+        with scope("factor.tail"):
+            for ch in sched.scan_chunks:
+                (vals, nper), _ = jax.lax.scan(
+                    scan_body, (vals, nper),
+                    (jnp.asarray(ch.dsl), jnp.asarray(ch.x_idx),
+                     jnp.asarray(ch.src_idx), jnp.asarray(ch.write_idx)))
 
-        return JaxFactors(vals=vals[:plan.total_slots],
-                          inode_perm=inode[:plan.n], n_perturb=nper)
+        with scope("factor.stage"):
+            return JaxFactors(vals=vals[:plan.total_slots],
+                              inode_perm=inode[:plan.n], n_perturb=nper)
 
     return factor_fn
 
@@ -711,7 +720,9 @@ class RepeatedSolveEngine:
 
         def _refactor(a_data):
             # A.data -> M.data is a pure gather+scale (see api.analyze)
-            return factor_fn(a_data.astype(dtype)[src] * scl)
+            with scope("factor.stage"):
+                m_data = a_data.astype(dtype)[src] * scl
+            return factor_fn(m_data)
 
         _apply = make_permuted_apply(lu_solve, n, p, q, row_scale, col_scale,
                                      dtype=dtype)
@@ -848,25 +859,31 @@ class RepeatedSolveEngine:
 
             def cond(carry):
                 _, _, resid, alive, _, it = carry
-                return (it < max_iter + 1) & jnp.any(alive & (resid > tol))
+                with scope("solve.residual"):
+                    return ((it < max_iter + 1)
+                            & jnp.any(alive & (resid > tol)))
 
             def body(carry):
                 x, r, resid, alive, n_ref, it = carry
-                need = alive & (resid > tol)
-                x2 = x + apply_b(vals, inode_perm, r).astype(rdtype)
-                r2 = b - matvec(a_vals, x2)
-                resid2 = jnp.sum(jnp.abs(r2), axis=1) / bnorm
-                # iteration 0 IS the base solve: accepted unconditionally
-                # (like the old explicit pre-loop solve), so a NaN/inf base
-                # residual surfaces in x instead of masking back to 0
-                improved = (resid2 < resid) | (it == 0)
-                upd = need & improved
-                x = jnp.where(expand(upd), x2, x)
-                r = jnp.where(expand(upd), r2, r)
-                resid = jnp.where(upd, resid2, resid)
-                alive = alive & (improved | ~need)
-                n_ref = n_ref + (upd & (it > 0))     # iteration 0 ≡ solve
-                return x, r, resid, alive, n_ref, it + 1
+                with scope("solve.subst"):
+                    dx = apply_b(vals, inode_perm, r)
+                with scope("solve.residual"):
+                    need = alive & (resid > tol)
+                    x2 = x + dx.astype(rdtype)
+                    r2 = b - matvec(a_vals, x2)
+                    resid2 = jnp.sum(jnp.abs(r2), axis=1) / bnorm
+                    # iteration 0 IS the base solve: accepted
+                    # unconditionally (like the old explicit pre-loop
+                    # solve), so a NaN/inf base residual surfaces in x
+                    # instead of masking back to 0
+                    improved = (resid2 < resid) | (it == 0)
+                    upd = need & improved
+                    x = jnp.where(expand(upd), x2, x)
+                    r = jnp.where(expand(upd), r2, r)
+                    resid = jnp.where(upd, resid2, resid)
+                    alive = alive & (improved | ~need)
+                    n_ref = n_ref + (upd & (it > 0))  # iteration 0 ≡ solve
+                    return x, r, resid, alive, n_ref, it + 1
 
             x, r, resid, alive, n_ref, it = jax.lax.while_loop(
                 cond, body, (x, r, resid, alive, n_ref, jnp.int32(0)))

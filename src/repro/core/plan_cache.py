@@ -43,7 +43,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import time
 import zipfile
 from collections import OrderedDict
 
@@ -56,6 +55,7 @@ from .symbolic import Symbolic
 from .plan import FactorPlan, NodePlan, Edge
 from .options import HyluOptions, plan_options_key, plan_fingerprint
 from .analysis import Analysis, analyze
+from .tracing import span
 
 FORMAT_VERSION = 1
 # Sentinel: resolved to <cache root>/plan_cache at PlanCache construction
@@ -199,9 +199,18 @@ def load_analysis(path: str, opts: HyluOptions | None = None,
     artifact on every plan-affecting field (validated via the persisted
     options key).  ``expected_fingerprint`` additionally pins the artifact
     to a specific content address.  Raises ``PlanCacheFormatError`` when
-    the artifact cannot be trusted."""
-    opts = opts or HyluOptions()
-    t0 = time.perf_counter()
+    the artifact cannot be trusted.  Its ``timings`` hold the seconds of
+    this load (``load``, host span ``hylu.analyze.load``) and of the
+    original analysis (``analyzed_total``)."""
+    t = {}
+    with span("analyze.load", into=t, key="load"):
+        an = _read_analysis(path, opts or HyluOptions(), expected_fingerprint)
+    an.timings.update(load=t["load"], total=t["load"])
+    return an
+
+
+def _read_analysis(path: str, opts: HyluOptions,
+                   expected_fingerprint: str | None) -> Analysis:
     try:
         z = np.load(path, allow_pickle=False)
         meta = json.loads(str(z["meta"][()]))
@@ -283,9 +292,7 @@ def load_analysis(path: str, opts: HyluOptions | None = None,
         padded_flops=float(pm["padded_flops"]),
         row_perm_slots=z["plan_row_perm_slots"])
 
-    load_s = time.perf_counter() - t0
-    timings = {"load": load_s, "total": load_s,
-               "analyzed_total": float(meta["timings"].get("total", 0.0))}
+    timings = {"analyzed_total": float(meta["timings"].get("total", 0.0))}
     return Analysis(
         n=n, opts=opts, match=match, q=z["q"], p=z["p"],
         ordering_name=meta["ordering_name"], choice=choice, sym=sym,
@@ -360,16 +367,16 @@ class PlanCache:
         path = self.path_for(fp)
         if path is not None and os.path.exists(path):
             try:
-                t0 = time.perf_counter()
-                an = load_analysis(path, opts=opts, expected_fingerprint=fp)
-                self.stats["load_s"] += time.perf_counter() - t0
+                with span("plan_cache.load", into=self.stats, key="load_s"):
+                    an = load_analysis(path, opts=opts,
+                                       expected_fingerprint=fp)
                 self.stats["disk_hits"] += 1
             except PlanCacheFormatError:
                 an = None                     # untrusted artifact: re-analyze
         if an is None:
-            t0 = time.perf_counter()
-            an = analyze(a, opts)
-            self.stats["analyze_s"] += time.perf_counter() - t0
+            with span("plan_cache.analyze", into=self.stats,
+                      key="analyze_s"):
+                an = analyze(a, opts)
             self.stats["misses"] += 1
             self.stats["analyze_calls"] += 1
             if path is not None:

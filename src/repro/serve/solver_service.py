@@ -53,7 +53,6 @@ Every request therefore receives exactly one terminal result:
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import numpy as np
 
@@ -62,6 +61,7 @@ from repro.core.options import (HyluOptions, plan_fingerprint, np_dtype,
                                 resolve_dtype_names, resolve_retry_perturb)
 from repro.core.plan_cache import PlanCache, DEFAULT_CACHE_DIR
 from repro.core.batched import factor_batched, solve_batched
+from repro.core.tracing import span
 
 
 # ------------------------------------------------------------ error taxonomy
@@ -321,31 +321,30 @@ class SolverService:
         ``requests`` — one terminal result per request (rejected / failed /
         quarantined results carry a typed ``error``; this method does not
         raise for per-request problems)."""
-        t0 = time.perf_counter()
-        reqs: list = []
-        results: list = [None] * len(requests)
-        for i, r in enumerate(requests):
-            if not isinstance(r, SolveRequest):
-                a, b = r
-                r = SolveRequest(a=a, b=b)
-            a, b, err = validate_request(r.a, r.b)
-            if err is not None:
-                self.stats["rejected"] += 1
-                results[i] = SolveResult(status=STATUS_REJECTED, error=err,
-                                         tag=r.tag)
-                reqs.append(None)
-                continue
-            reqs.append(SolveRequest(a=a, b=b, tag=r.tag,
-                                     factor_dtype=r.factor_dtype))
+        with span("service.solve_batch", into=self.stats, key="solve_s"):
+            reqs: list = []
+            results: list = [None] * len(requests)
+            for i, r in enumerate(requests):
+                if not isinstance(r, SolveRequest):
+                    a, b = r
+                    r = SolveRequest(a=a, b=b)
+                a, b, err = validate_request(r.a, r.b)
+                if err is not None:
+                    self.stats["rejected"] += 1
+                    results[i] = SolveResult(status=STATUS_REJECTED, error=err,
+                                             tag=r.tag)
+                    reqs.append(None)
+                    continue
+                reqs.append(SolveRequest(a=a, b=b, tag=r.tag,
+                                         factor_dtype=r.factor_dtype))
 
-        valid = [i for i, r in enumerate(reqs) if r is not None]
-        self._group_and_dispatch(reqs, valid, results)
-        self._escalate(reqs, results)
+            valid = [i for i, r in enumerate(reqs) if r is not None]
+            self._group_and_dispatch(reqs, valid, results)
+            self._escalate(reqs, results)
 
-        self.stats["requests"] += len(reqs)
-        self.stats["refine_failed"] += sum(
-            1 for r in results if r is not None and r.refine_failed)
-        self.stats["solve_s"] += time.perf_counter() - t0
+            self.stats["requests"] += len(reqs)
+            self.stats["refine_failed"] += sum(
+                1 for r in results if r is not None and r.refine_failed)
         return results
 
     def _opts_for(self, req: SolveRequest, retry_attempt: int = 0):

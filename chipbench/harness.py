@@ -184,6 +184,8 @@ class Context:
     peaks: dict           # the device's row of peaks.json
     factor_bytes: int     # item size of the factor dtype
     values_bytes: int     # item size of the staged (refine) dtype
+    scoped: object = None  # scopes.Window: the window's events, the
+    #                        solver's host spans and scoped ops, its steps
 
 
 def progress(t_start: float, what: str) -> None:
@@ -289,6 +291,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
 
     summary = None
     if trace:
+        from . import scopes
         tdir = tempfile.mkdtemp(prefix="chipbench-trace-")
         try:
             jax.profiler.start_trace(tdir)
@@ -297,7 +300,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
                     traffic.window(seconds, True)
             finally:
                 jax.profiler.stop_trace()
-            events = events_from_xplane(find_xplane(tdir))
+            path = find_xplane(tdir)
+            events = events_from_xplane(path)
+            hspans, ops = scopes.collect(path)
         finally:
             shutil.rmtree(tdir, ignore_errors=True)
         if save_events:
@@ -334,12 +339,17 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
                       work=work.Counts.of(analysis), peaks=peaks,
                       factor_bytes=np_dtype(opts.factor_dtype).itemsize,
                       values_bytes=np_dtype(
-                          config["precision"]["refine_dtype"]).itemsize)
+                          config["precision"]["refine_dtype"]).itemsize,
+                      scoped=scopes.Window(events, hspans, ops,
+                                           counters.get("steps", 0)))
+        memo["last_context"] = ctx
         for m in cell.per_layer:
             reader = load_module(BENCH / "metrics" / f"{m['name']}.py")
             v = reader.read(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
+        for line in scopes.describe(ctx.scoped.attribution):
+            print(f"chipbench: {line}", file=sys.stderr, flush=True)
     else:
         e2e = dict(out.end_to_end, setup_s=setup_s)
         for m in cell.end_to_end:

@@ -1,12 +1,10 @@
-"""Device milliseconds per run of the batched refactor program
-(``RepeatedSolveEngine.refactor_batched``, module ``jit__refactor``) in the
-traced window."""
+"""Device milliseconds of the factor per traced step: the module runs that
+hold a ``hylu.factor.`` scope (``scopes.attribute``), whatever programs run
+it, over the steps of the traced window."""
 
-PROGRAM = "_refactor"
+from chipbench.scopes import family_seconds
 
 
 def read(ctx):
-    if ctx.summary is None:
-        return None
-    seconds, runs = ctx.summary.program(PROGRAM)
-    return 1e3 * seconds / runs if runs and seconds > 0 else None
+    seconds = family_seconds(ctx, "factor", "factor_ms.sweep")
+    return None if seconds is None else 1e3 * seconds

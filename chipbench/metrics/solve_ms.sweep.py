@@ -1,12 +1,11 @@
-"""Device milliseconds per run of the fused substitution + residual +
-refinement program (``refined_batched_solver``, module
-``jit_solve_refined``) in the traced window."""
+"""Device milliseconds of the solve (substitution, residual and
+refinement) per traced step: the module runs that hold a ``hylu.solve.``
+scope (``scopes.attribute``), whatever programs run it, over the steps of
+the traced window."""
 
-PROGRAM = "solve_refined"
+from chipbench.scopes import family_seconds
 
 
 def read(ctx):
-    if ctx.summary is None:
-        return None
-    seconds, runs = ctx.summary.program(PROGRAM)
-    return 1e3 * seconds / runs if runs and seconds > 0 else None
+    seconds = family_seconds(ctx, "solve", "solve_ms.sweep")
+    return None if seconds is None else 1e3 * seconds

@@ -20,20 +20,32 @@ holds count once.  :func:`phases` gives the eight per-phase numbers of a
 traced sweep window; each is None where the trace holds nothing to read
 (a solver without spans and scopes).
 
-The benchmark's harness does not collect these events yet, so ``main``
-runs traced cells itself and reads them from the same trace file:
+:func:`attribute` gives each module run of the window to the factor or the
+solve by the scopes of the operations it holds, whatever the program is
+called or however the work is split into programs (a trace without
+scopes gives nothing); :func:`family_seconds` and :func:`phase` are what
+the per-layer readers call, and give a one-line reason on standard error
+where they read nothing.  The harness collects
+``(hspans, ops)`` in every traced run and hands them to the readers as a
+:class:`Window`.
+
+``main`` runs traced cells in one process and prints what the readers
+read from each window, by scope and by module:
 
     python3 chipbench/scopes.py --workload circuit-sweep --seeds 1,2 \\
         [--untraced] [--seconds s] [--config JSON] [--traffic JSON] \\
         [--save-events FILE]
 
 Each run prints one JSON line: the harness's metrics, the median step
-time, and for a traced run the eight phase numbers, each program's scope
-coverage with what is left over, and the seconds of every host span.
+time, and for a traced run the eight phase numbers, the attribution of
+module runs to the factor and the solve, each program's scope coverage
+with what is left over, and the seconds of every host span.
 """
 from __future__ import annotations
 
 import bisect
+import dataclasses
+import functools
 import json
 import os
 import re
@@ -48,17 +60,22 @@ HOST_PREFIX = "hylu."
 SCOPE = re.compile(r"hylu\.[a-z_]+\.[a-z_]+")
 FACTOR_PROGRAM = "_refactor"
 SOLVE_PROGRAM = "solve_refined"
-#: metric name -> (scope, program whose runs it is divided by)
+#: metric name -> the scope whose device time it reads
 DEVICE_METRICS = {
-    "factor_stage_ms.sweep": ("hylu.factor.stage", FACTOR_PROGRAM),
-    "factor_panel_ms.sweep": ("hylu.factor.panel", FACTOR_PROGRAM),
-    "factor_edge_ms.sweep": ("hylu.factor.edge", FACTOR_PROGRAM),
-    "factor_tail_ms.sweep": ("hylu.factor.tail", FACTOR_PROGRAM),
-    "solve_subst_ms.sweep": ("hylu.solve.subst", SOLVE_PROGRAM),
-    "solve_residual_ms.sweep": ("hylu.solve.residual", SOLVE_PROGRAM),
+    "factor_stage_ms.sweep": "hylu.factor.stage",
+    "factor_panel_ms.sweep": "hylu.factor.panel",
+    "factor_edge_ms.sweep": "hylu.factor.edge",
+    "factor_tail_ms.sweep": "hylu.factor.tail",
+    "solve_subst_ms.sweep": "hylu.solve.subst",
+    "solve_residual_ms.sweep": "hylu.solve.residual",
 }
 STEP_SPAN = "hylu.factor_batched"
 STAGE_SPAN = "hylu.stage"
+#: family -> the scope prefix of its operations
+FAMILIES = {"factor": "hylu.factor.", "solve": "hylu.solve."}
+#: the harness span of each step's solve, which returns host arrays and so
+#: waits for the step's device work, however the factor is dispatched
+SOLVE_SPAN = trace.SPAN_PREFIX + "solve_batched"
 
 
 def scope_of(op_name: str | None) -> str | None:
@@ -331,25 +348,204 @@ def window(events) -> tuple[int, int]:
     return min(s for s, _ in dev), max(e for _, e in dev)
 
 
-def phases(events, hspans, ops) -> dict:
+def incomplete(events) -> str | None:
+    """Why the trace cannot hold all of the window's device work, or None.
+
+    Each ``cb:solve_batched`` span returns the step's answers to the host,
+    so it cannot end before the step's refactor and solve have run on the
+    device, and the device cannot run the step's solve before the span
+    starts.  So each line of device events ("XLA Modules", "XLA Ops") of
+    a complete trace starts before the window's first such span ends and
+    ends after its last one starts.  A trace that lost events at either
+    end does not; one without those spans cannot be judged and passes."""
+    lo, hi = window(events)
+    solves = [(s, e) for k, n, s, e, _ in events
+              if k == "span" and n == SOLVE_SPAN and lo <= s < hi]
+    firsts = [e for _, e in solves]
+    lasts = [s for s, _ in solves]
+    reach = {}
+    for k, _, s, e, dev in events:
+        if k in ("module", "op") and e > lo and s < hi:
+            r = reach.setdefault((dev, k), [s, e])
+            r[0], r[1] = min(r[0], s), max(r[1], e)
+    for (dev, k), (first, last) in sorted(reach.items()):
+        line = dev + " " + (trace.MODULES_LINE if k == "module"
+                            else trace.OPS_LINE)
+        if firsts and first >= min(firsts):
+            return (f"trace incomplete: the {line} events start "
+                    f"{(first - lo) / 1e9:.6f} s into the window, after the "
+                    f"first {SOLVE_SPAN} span ends at "
+                    f"{(min(firsts) - lo) / 1e9:.6f} s")
+        if lasts and last <= max(lasts):
+            return (f"trace incomplete: the {line} events end "
+                    f"{(last - lo) / 1e9:.6f} s into the window, before the "
+                    f"last {SOLVE_SPAN} span starts at "
+                    f"{(max(lasts) - lo) / 1e9:.6f} s")
+    return None
+
+
+def family_of(scope: str | None) -> str | None:
+    """The family (``factor``/``solve``) whose scopes hold ``scope``."""
+    if scope:
+        for family, prefix in FAMILIES.items():
+            if scope.startswith(prefix):
+                return family
+    return None
+
+
+@dataclasses.dataclass
+class Attribution:
+    """The window's module runs given to the factor and the solve.
+
+    Seconds are device seconds inside the window and runs count the runs
+    that start in it, both averaged over the devices, as
+    :class:`trace.Summary` does."""
+    seconds: dict          # family -> device seconds
+    modules: dict          # family -> {module: [runs, seconds, op events]}
+    scoped_ops: dict       # family -> operations with one of its scopes
+    other: dict            # module -> seconds of runs of no family
+
+
+def _split(lo: int, hi: int, held) -> dict:
+    """{family: [ns, op events]} of one module run [lo, hi) that holds the
+    operations of more than one family; ``held`` is its operations as
+    ``(family or None, start, end)``.
+
+    Each family holds its scoped operations and the unscoped ones that
+    enclose operations of it alone (a loop around its body).  Every other
+    instant of the run, a copy with no ``op_name`` or an idle gap, goes to
+    the family that holds the one before it (the first family, before any),
+    so the families share the whole run between them."""
+    starts = {}
+    for family, s, _ in held:
+        if family:
+            starts.setdefault(family, []).append(s)
+    for v in starts.values():
+        v.sort()
+    spans = [(s, e, f) for f, s, e in held if f]
+    for f0, s, e in held:
+        if f0 is None:
+            inside = [f for f, v in starts.items()
+                      if bisect.bisect_left(v, s) < bisect.bisect_left(v, e)]
+            if len(inside) == 1:
+                spans.append((s, e, inside[0]))
+    spans.sort()
+    out = {f: [0, 0] for f in starts}
+    cuts = [max(lo, s) for s, _, _ in spans[1:]] + [hi]
+    edge = lo
+    for (_, _, f), cut in zip(spans, cuts):
+        if cut > edge:
+            out[f][0] += cut - edge
+            edge = cut
+    owner = [f for _, _, f in spans]
+    at = [max(lo, s) for s, _, _ in spans]
+    for f, s, _ in held:
+        out[owner[max(bisect.bisect_right(at, s) - 1, 0)]][1] += 1
+    return out
+
+
+def attribute(events, ops) -> Attribution:
+    """Give each module run in the window to a family by the scopes of the
+    operations it holds (on the same device, starting inside it), whatever
+    the program is called or however the work is split into programs.
+
+    A run that holds one family's scopes counts whole for it, operations
+    without an ``op_name`` and idle gaps included.  A run that holds both
+    families is shared between them by :func:`_split`, so it counts whole
+    too.  A run that holds no ``hylu.`` scope is in ``other``."""
+    lo, hi = window(events)
+    devices = sorted({t for k, _, _, _, t in events if k in ("module", "op")})
+    nd = max(len(devices), 1)
+    runs = {dev: [] for dev in devices}
+    for k, n, s, e, t in events:
+        if k == "module":
+            runs[t].append((s, e, n))
+    on_dev = {dev: [] for dev in devices}
+    for o in ops:
+        on_dev.setdefault(o[5], []).append(o)
+    # whole nanoseconds and counts summed over the devices, so that work
+    # split over more runs or programs sums to the same total exactly
+    modules = {f: {} for f in FAMILIES}
+    scoped_ops = {f: 0 for f in FAMILIES}
+    other = {}
+
+    def add(family, name, started, ns, n_ops):
+        m = modules[family].setdefault(name, [0, 0, 0])
+        m[0] += started
+        m[1] += ns
+        m[2] += n_ops
+
+    for dev in devices:
+        mods = sorted(runs[dev])
+        starts = [m[0] for m in mods]
+        held = [[] for _ in mods]      # (family or None, start, end)
+        for _, _, scope, s, e, _ in on_dev.get(dev, []):
+            family = family_of(scope)
+            if family and s < hi and e > lo:
+                scoped_ops[family] += 1
+            i = bisect.bisect_right(starts, s) - 1
+            if i >= 0 and s < mods[i][1]:
+                held[i].append((family, s, e))
+        for i, (s, e, name) in enumerate(mods):
+            clip = trace._clip([(s, e)], lo, hi)
+            if not clip:
+                continue
+            run_lo, run_hi = clip[0]
+            started = int(lo <= s < hi)
+            families = {f for f, _, _ in held[i] if f}
+            if not families:
+                other[name] = other.get(name, 0) + run_hi - run_lo
+            elif len(families) == 1:
+                add(families.pop(), name, started, run_hi - run_lo,
+                    len(held[i]))
+            else:
+                for f, (ns, n_ops) in _split(run_lo, run_hi,
+                                             held[i]).items():
+                    add(f, name, started, ns, n_ops)
+    return Attribution(
+        seconds={f: sum(m[1] for m in modules[f].values()) / 1e9 / nd
+                 for f in FAMILIES},
+        modules={f: {n: [r / nd, ns / 1e9 / nd, o / nd]
+                     for n, (r, ns, o) in sorted(modules[f].items())}
+                 for f in FAMILIES},
+        scoped_ops=scoped_ops,
+        other={n: ns / 1e9 / nd for n, ns in other.items()})
+
+
+def describe(att: Attribution, top: int = 5) -> list:
+    """Lines that say which module runs each family was given."""
+    out = []
+    for family in FAMILIES:
+        mods = "; ".join(
+            f"{n} {r:g} runs {s:.6f} s {o / r if r else o:.0f} op events a run"
+            for n, (r, s, o) in sorted(att.modules[family].items()))
+        out.append(f"{family}: {mods or 'no module'}")
+    if att.other:
+        ranked = sorted(att.other.items(), key=lambda kv: -kv[1])[:top]
+        out.append("no family: " + "; ".join(f"{n} {s:.6f} s"
+                                             for n, s in ranked))
+    return out
+
+
+def phases(events, hspans, ops, steps: int | None = None) -> dict:
     """The eight per-phase numbers of a traced sweep window, from the
     harness's flattened ``events`` and this module's ``hspans``/``ops``:
-    device ms per program run in each scope, host ms per step in
-    ``hylu.stage``, and device idle ms per step under an open ``hylu.``
-    span.  A step is a ``hylu.factor_batched`` span that starts in the
-    window.  A value is None where the trace has nothing to read: no
-    scoped operation, or no step span.  A scope the plan lacks (the
-    scanned tail of a plan without one) reads 0."""
-    summary = trace.reduce(events)
+    device ms per step in each scope, host ms per step in ``hylu.stage``,
+    and device idle ms per step under an open ``hylu.`` span.  ``steps``
+    is the window's step count; without it, the ``hylu.factor_batched``
+    spans that start in the window.  A value is None where the trace has
+    nothing to read: no scoped operation, or no step.  A scope the plan
+    lacks (the scanned tail of a plan without one) reads 0."""
     lo, hi = window(events)
+    if steps is None:
+        steps = sum(1 for n, s, _ in hspans
+                    if n == STEP_SPAN and lo <= s < hi)
     scoped = any(o[2] for o in ops)
     sec = scope_seconds(ops, lo, hi)
     out = {}
-    for metric, (scope, program) in DEVICE_METRICS.items():
-        _, runs = summary.program(program)
-        out[metric] = (1e3 * sec.get(scope, 0.0) / runs
-                       if runs and scoped else None)
-    steps = sum(1 for n, s, _ in hspans if n == STEP_SPAN and lo <= s < hi)
+    for metric, scope in DEVICE_METRICS.items():
+        out[metric] = (1e3 * sec.get(scope, 0.0) / steps
+                       if steps and scoped else None)
     idle = solver_idle_seconds(ops, hspans, lo, hi)
     out["host_stage_ms.sweep"] = (
         1e3 * span_seconds(hspans, STAGE_SPAN, lo, hi) / steps
@@ -357,6 +553,76 @@ def phases(events, hspans, ops) -> dict:
     out["solver_idle_ms.sweep"] = (1e3 * idle / steps
                                    if steps and idle is not None else None)
     return out
+
+
+class Window:
+    """One traced window as the per-layer readers get it: the harness's
+    flattened events, the solver's host spans and scoped operations, and
+    the steps the window ran; what they reduce to is worked out once."""
+
+    def __init__(self, events, hspans, ops, steps: int):
+        self.events, self.hspans, self.ops = events, hspans, ops
+        self.steps = steps
+
+    @functools.cached_property
+    def incomplete(self) -> str | None:
+        return incomplete(self.events)
+
+    @functools.cached_property
+    def attribution(self) -> Attribution:
+        return attribute(self.events, self.ops)
+
+    @functools.cached_property
+    def phases(self) -> dict:
+        return phases(self.events, self.hspans, self.ops, self.steps)
+
+
+def nothing(metric: str, why: str) -> None:
+    """Say on standard error why ``metric`` reads nothing; returns None."""
+    print(f"chipbench: {metric} reads nothing: {why}", file=sys.stderr,
+          flush=True)
+
+
+def _unreadable(ctx) -> str | None:
+    win = ctx.scoped
+    if win is None:
+        return "no traced window"
+    if not win.steps:
+        return "no traced step"
+    return win.incomplete
+
+
+def family_seconds(ctx, family: str, metric: str) -> float | None:
+    """Device seconds of ``family`` per traced step of the window in
+    ``ctx`` (:func:`attribute`); None, with the reason on standard error,
+    where the window has nothing to read."""
+    why = _unreadable(ctx)
+    if why is None:
+        att = ctx.scoped.attribution
+        prefix = FAMILIES[family]
+        if not any(att.scoped_ops.values()):
+            why = "no scoped op: none carries a hylu. scope"
+        elif not att.scoped_ops[family]:
+            why = f"no scoped op of the family: none carries a {prefix} scope"
+        elif att.seconds[family] <= 0:
+            why = f"no module of the family: no run holds a {prefix} scope"
+    if why is not None:
+        return nothing(metric, why)
+    return ctx.scoped.attribution.seconds[family] / ctx.scoped.steps
+
+
+def phase(ctx, metric: str) -> float | None:
+    """One of :func:`phases`' numbers for the window in ``ctx``; None, with
+    the reason on standard error, where the window has nothing to read."""
+    why = _unreadable(ctx)
+    if why is None:
+        value = ctx.scoped.phases[metric]
+        if value is not None:
+            return value
+        why = ("no scoped op: none carries a hylu. scope"
+               if metric in DEVICE_METRICS else
+               "no hylu. host span or no device op")
+    return nothing(metric, why)
 
 
 def save(path: str, events, hspans, ops) -> None:
@@ -400,17 +666,6 @@ def main(argv=None) -> int:
                          "and scoped operations to this JSON file")
     args = ap.parse_args(argv)
 
-    # the harness reads its events through trace.events_from_xplane; this
-    # wrapper reads the scopes from the same trace file before it is removed
-    seen = {}
-    read = trace.events_from_xplane
-
-    def events_and_scopes(path):
-        seen["scoped"] = collect(path)
-        seen["events"] = read(path)
-        return seen["events"]
-
-    trace.events_from_xplane = events_and_scopes
     memo = {}
     for seed in [int(s) for s in args.seeds.split(",")]:
         for traced in (True, False) if args.untraced else (True,):
@@ -426,11 +681,16 @@ def main(argv=None) -> int:
                     "median_step_s": _median_step_s(memo["last_counters"]),
                     "metrics": r["metrics"], "device": r["device"]}
             if traced:
-                events = seen.pop("events")
-                hspans, ops = seen.pop("scoped")
+                win = memo["last_context"].scoped
+                events, hspans, ops = win.events, win.hspans, win.ops
                 lo, hi = window(events)
                 line["breakdown"] = r["breakdown"]
-                line["phases"] = phases(events, hspans, ops)
+                line["phases"] = win.phases
+                line["attribution"] = dataclasses.asdict(win.attribution)
+                line["incomplete"] = win.incomplete
+                line["events"] = {"ops": len(ops), "hspans": len(hspans),
+                                  "modules": sum(e[0] == "module"
+                                                 for e in events)}
                 line["coverage"] = {
                     "factor": coverage(ops, FACTOR_PROGRAM, "hylu.factor.",
                                        lo, hi),

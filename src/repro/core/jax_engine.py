@@ -270,28 +270,49 @@ def _make_factor_fn_bucketed(plan: FactorPlan, perturb_eps, dtype,
                     vals = vals.at[jnp.asarray(eb.write_idx)].add(w_vals)
 
         # ---- scanned width-1 suffix: one traced body per chunk -----------
-        def scan_body(carry, xs):
-            vals, nper = carry
-            dsl, x_i, s_i, w_i = xs
-            d = vals[dsl]
+        def level_step(buf, nper, dsl, x_i, s_i, w_i):
+            d = buf[dsl]
             small = jnp.abs(d) < eps_p          # pads read the huge sentinel
             d = jnp.where(small, jnp.where(d >= 0, eps_p, -eps_p), d)
-            vals = vals.at[dsl].set(d)
+            buf = buf.at[dsl].set(d)
             nper = nper + jnp.sum(small).astype(jnp.int32)
-            S = vals[s_i]                       # (E, 1+M)
-            X = vals[x_i]                       # (E,)
+            S = buf[s_i]                        # (E, 1+M)
+            X = buf[x_i]                        # (E,)
             lts = X / S[:, 0]
             upd = jnp.concatenate([(lts - X)[:, None],
                                    -lts[:, None] * S[:, 1:]], axis=1)
-            vals = vals.at[w_i].add(upd)
-            return (vals, nper), None
+            buf = buf.at[w_i].add(upd)
+            return buf, nper
+
+        def run_chunk(vals, nper, ch):
+            """The chunk's windows, each over its own small buffer (see
+            ``ScanChunk``); a window's levels run as a loop of their own
+            length, so no padded level does any work."""
+            levels = tuple(jnp.asarray(a) for a in
+                           (ch.dsl, ch.x_idx, ch.src_idx, ch.write_idx))
+            g = ch.n_slots
+
+            def level(i, carry):
+                return level_step(*carry, *(a[i] for a in levels))
+
+            def window(carry, xs):
+                vals, nper = carry
+                gidx, bounds = xs
+                buf, nper = jax.lax.fori_loop(bounds[0], bounds[1], level,
+                                              (vals[gidx], nper))
+                # gidx[:g] is unique but for its scratch pads, which write
+                # back the scratch value they gathered
+                vals = vals.at[gidx[:g]].set(buf[:g])
+                return (vals, nper), None
+
+            (vals, nper), _ = jax.lax.scan(
+                window, (vals, nper),
+                (jnp.asarray(ch.gidx), jnp.asarray(ch.windows)))
+            return vals, nper
 
         with scope("factor.tail"):
             for ch in sched.scan_chunks:
-                (vals, nper), _ = jax.lax.scan(
-                    scan_body, (vals, nper),
-                    (jnp.asarray(ch.dsl), jnp.asarray(ch.x_idx),
-                     jnp.asarray(ch.src_idx), jnp.asarray(ch.write_idx)))
+                vals, nper = run_chunk(vals, nper, ch)
 
         with scope("factor.stage"):
             return JaxFactors(vals=vals[:plan.total_slots],

@@ -239,7 +239,7 @@ def memory_stats(plan: FactorPlan, bulk_min_width: int = 8, k: int = 1,
                     + eb.x_idx.nbytes + eb.write_idx.nbytes)
     for c in sched.scan_chunks:
         idx += (c.dsl.nbytes + c.x_idx.nbytes + c.src_idx.nbytes
-                + c.write_idx.nbytes)
+                + c.write_idx.nbytes + c.windows.nbytes + c.gidx.nbytes)
     panel = plan.total_slots * dtype_bytes
     workspace = sched.n_ext * dtype_bytes
     batched = k * (sched.n_ext + 2 * plan.n) * dtype_bytes

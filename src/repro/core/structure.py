@@ -381,6 +381,13 @@ def block_solve_schedule(plan: FactorPlan, upper: bool,
 # write-only scratch slot.  All three live past the end of the value buffer.
 _PAD_ZERO, _PAD_ONE, _PAD_SCRATCH = 0, 1, 2     # offsets past total_slots
 
+#: Most distinct real slots one window of the scanned width-1 tail may
+#: gather (``_tail_windows``): a level then streams a (K, ≤ cap + 3)
+#: buffer instead of the whole (K, n_ext) factor.  Sized from
+#: circuit_10k's tail (83 windows of ~86 levels; 2 MB a buffer in float32
+#: at K=64); PERF.md keeps the measurements.
+TAIL_WINDOW_SLOTS = 8192
+
 
 def _pad_dim(v: int) -> int:
     """Round a bucket dimension up to the next power of two (small static
@@ -498,21 +505,43 @@ class LevelStep:
 @dataclasses.dataclass
 class ScanChunk:
     """A run of consecutive all-width-1 levels executed as ONE ``lax.scan``
-    whose body is traced once — the trace-size endgame for the long narrow
-    tail of circuit-style level schedules.
+    over windows whose body is traced once — the trace-size endgame for
+    the long narrow tail of circuit-style level schedules.
+
+    A level touches a few thousand slots at most, so it must not stream
+    the whole factor: a loop step that gathers from and scatter-adds into
+    the full (K, n_ext) carry pays for every slot of it.  The chunk's
+    levels are therefore cut into consecutive windows (``_tail_windows``).
+    Window w owns a small buffer gathered from the global slots
+    ``gidx[w]`` before its levels run: positions ``[0, n_slots)`` hold the
+    sorted, unique real slots its levels touch (pads → the global scratch
+    slot) and are written back after; positions ``n_slots + 0/1/2`` are
+    the window's own zero, identity-pivot and scratch entries, gathered
+    from the global sentinels.  The four level index arrays hold
+    positions in that buffer.
 
     All levels in the chunk are padded to shared (D, E, M) shapes; the
-    sentinel slots make the padding maskless (padded diagonal slots read
+    sentinel entries make the padding maskless (padded diagonal slots read
     the huge identity-pivot sentinel — never "small", rewritten verbatim;
     padded gathers read 0 → zero multipliers and zero updates; padded
-    writes land in scratch)."""
+    writes land in scratch; the zero entry is never written)."""
     lv0: int
     lv1: int                # exclusive
-    dsl: np.ndarray         # (L, D) diag slots, pads → one slot
-    x_idx: np.ndarray       # (L, E) multiplier gathers, pads → zero slot
+    dsl: np.ndarray         # (L, D) diag positions, pads → one entry
+    x_idx: np.ndarray       # (L, E) multiplier gathers, pads → zero entry
     src_idx: np.ndarray     # (L, E, 1+M) source rows [diag | U], pads:
-                            # col 0 → one slot, cols 1: → zero slot
+                            # col 0 → one entry, cols 1: → zero entry
     write_idx: np.ndarray   # (L, E, 1+M) combined scatter, pads → scratch
+    windows: np.ndarray     # (W, 2) each window's [first, last) level,
+                            # counted from lv0
+    gidx: np.ndarray        # (W, n_slots + 3) global slots of each
+                            # window's buffer: real slots (pads → global
+                            # scratch), then global zero / one / scratch
+
+    @property
+    def n_slots(self) -> int:
+        """Buffer positions before the window's zero/one/scratch entries."""
+        return self.gidx.shape[1] - 3
 
 
 @dataclasses.dataclass
@@ -526,6 +555,51 @@ class BucketSchedule:
     n_bulk_levels: int
     steps: list             # list[LevelStep], unrolled level prefix
     scan_chunks: list       # list[ScanChunk], the scanned width-1 suffix
+
+
+def _tail_windows(total: int, arrays: tuple):
+    """Cut a scan chunk's levels into windows and rewrite its index arrays
+    as positions in each window's buffer (see ``ScanChunk``).
+
+    ``arrays`` are the chunk's (L, ...) global-slot index arrays; entries
+    ``>= total`` are the zero / one / scratch sentinels.  A window grows
+    level by level until its distinct real slots would pass
+    ``TAIL_WINDOW_SLOTS`` (a level that alone passes it is a window of its
+    own).  Returns ``(windows (W, 2), gidx (W, G + 3), local arrays)`` with
+    G the largest window's slot count."""
+    n_lv = arrays[0].shape[0]
+    level_slots = []
+    for l in range(n_lv):
+        s = np.concatenate([a[l].ravel() for a in arrays])
+        level_slots.append(np.unique(s[s < total]))
+    # stamp[s] == w marks slot s as already in window w
+    stamp = np.full(total, -1, dtype=np.int64)
+    bounds, count = [[0, 0]], 0
+    for l, s in enumerate(level_slots):
+        w = len(bounds) - 1
+        new = np.count_nonzero(stamp[s] != w)
+        if l > bounds[w][0] and count + new > TAIL_WINDOW_SLOTS:
+            bounds.append([l, l])
+            w, count, new = w + 1, 0, len(s)
+        stamp[s] = w
+        count += new
+        bounds[w][1] = l + 1
+    sets = [np.unique(np.concatenate(level_slots[l0:l1]))
+            for l0, l1 in bounds]
+    g = max(len(s) for s in sets)
+    gidx = np.full((len(sets), g + 3), total + _PAD_SCRATCH, dtype=np.int32)
+    gidx[:, g:] = total + np.arange(3)
+    # pos: global slot → buffer position; sentinel total + k → the
+    # window's own entry g + k
+    pos = np.zeros(total + 3, dtype=arrays[0].dtype)
+    pos[total:] = g + np.arange(3)
+    local = tuple(np.empty_like(a) for a in arrays)
+    for w, ((l0, l1), s) in enumerate(zip(bounds, sets)):
+        gidx[w, :len(s)] = s
+        pos[s] = np.arange(len(s))
+        for a, out in zip(arrays, local):
+            out[l0:l1] = pos[a[l0:l1]]
+    return np.asarray(bounds, dtype=np.int32), gidx, local
 
 
 def build_bucket_schedule(plan: FactorPlan,
@@ -677,16 +751,16 @@ def build_bucket_schedule(plan: FactorPlan,
                                edges=edges))
 
     # ------- scan chunks over the width-1 suffix ---------------------------
+    level_pairs: dict = {}
+    for key, pairs in edge_groups.items():
+        level_pairs.setdefault(key[0], []).extend(pairs)
+
     def _level_raw(lv):
-        """(diag_slots, [(x, src_row_base, m, col_map, toff, tw)]) of one
-        scanned level — everything is width 1."""
+        """(diag_slots, [(edge, target node)]) of one scanned level —
+        everything is width 1."""
         dsl = plan.row_perm_slots[
             [nodes[int(t)].r0 for t in plan.levels[lv]]].astype(np.int64)
-        epairs = []
-        for key, pairs in edge_groups.items():
-            if key[0] == lv:
-                epairs.extend(pairs)
-        return dsl, epairs
+        return dsl, level_pairs.get(lv, [])
 
     raw = {lv: _level_raw(lv) for lv in range(scan_start, n_levels)}
 
@@ -722,8 +796,11 @@ def build_bucket_schedule(plan: FactorPlan,
                 x_a[l, i] = toff + e.col_map[0]
                 wr_a[l, i, 0] = toff + e.col_map[0]
                 wr_a[l, i, 1:1 + m] = toff + e.col_map[1:]
-        chunks.append(ScanChunk(lv0=lv0, lv1=lv1, dsl=dsl_a, x_idx=x_a,
-                                src_idx=src_a, write_idx=wr_a))
+        windows, gidx, local = _tail_windows(total, (dsl_a, x_a, src_a, wr_a))
+        chunks.append(ScanChunk(lv0=lv0, lv1=lv1, dsl=local[0],
+                                x_idx=local[1], src_idx=local[2],
+                                write_idx=local[3], windows=windows,
+                                gidx=gidx))
 
     return BucketSchedule(n=n, total_slots=total, n_ext=total + 3,
                           zero_slot=zero, one_slot=one, scratch_slot=scr,
@@ -765,10 +842,14 @@ def bucket_stats(plan: FactorPlan, bulk_min_width: int = 8) -> dict:
             for arr in (eb.src_idx, eb.x_idx, eb.write_idx):
                 gathered += arr.size
                 real += int((arr < sched.total_slots).sum())
+    win_slots, windowed = [], 0
     for c in sched.scan_chunks:
         for arr in (c.dsl, c.x_idx, c.src_idx, c.write_idx):
             gathered += arr.size
-            real += int((arr < sched.total_slots).sum())
+            real += int((arr < c.n_slots).sum())
+        win_slots.extend((c.gidx < sched.total_slots).sum(axis=1).tolist())
+        lens = c.windows[:, 1] - c.windows[:, 0]
+        windowed += int(lens[lens > 1].sum())
     return dict(
         n_seq_nodes=n_seq,
         n_diag_buckets=n_diag,
@@ -776,6 +857,11 @@ def bucket_stats(plan: FactorPlan, bulk_min_width: int = 8) -> dict:
         n_edge_buckets=n_edge,
         n_scan_chunks=len(sched.scan_chunks),
         n_scanned_levels=n_scanned,
+        n_tail_windows=len(win_slots),
+        tail_window_slots_mean=(float(np.mean(win_slots)) if win_slots
+                                else 0.0),
+        tail_window_slots_max=max(win_slots, default=0),
+        tail_windowed_level_share=windowed / max(n_scanned, 1),
         bulk_node_coverage=1.0 - n_seq / max(plan.n_nodes, 1),
         pad_waste_frac=(gathered - real) / max(gathered, 1),
     )

@@ -89,3 +89,95 @@ def test_default_bulk_width_end_to_end(mode):
     st = factor(an, Ac)
     x, info = solve(st, b)
     assert info["residual"] < 1e-10, mode
+
+
+def _dependent_row_system(n=26):
+    """The perturbation test's system: two identical rows, so one pivot
+    collapses to round-off and must be perturbed."""
+    rng = np.random.default_rng(7)
+    a = sp.random(n, n, density=0.18,
+                  random_state=np.random.RandomState(3), format="lil")
+    a = a + sp.diags(rng.uniform(1, 2, n))
+    a[9, :] = a[4, :]
+    return CSR.from_scipy(a.tocsr())
+
+
+# (circuit n and seed, or None for the dependent-row system; tail window
+# cap; bulk_min_width; batch K).  A bulk_min_width above n scans every
+# level, so the tail does all of the factorization.
+WINDOW_CASES = {
+    "many-windows": ((60, 2), 48, 8, 1),
+    "one-window": ((60, 2), None, 8, 1),
+    "dup-writes": ((80, 0), 400, 8, 1),
+    "tiny-pivot": (None, 24, 64, 1),
+    "vmap-k3": ((60, 2), 48, 8, 3),
+}
+
+
+@pytest.mark.parametrize("case", list(WINDOW_CASES))
+def test_windowed_tail_parity(case, monkeypatch):
+    """The scanned width-1 tail runs window by window over small slot
+    buffers (``ScanChunk``); whatever the windows, it must give the
+    unrolled oracle's and ``ref_engine``'s values, pivots and
+    perturbation counts, also vmapped over K value sets."""
+    from repro.core import structure
+
+    system, cap, bmw, k = WINDOW_CASES[case]
+    if system is None:
+        Ac = _dependent_row_system()
+    else:
+        Ac = scenario_system("circuit", n=system[0], seed=system[1])[0]
+    if cap is not None:
+        monkeypatch.setattr(structure, "TAIL_WINDOW_SLOTS", cap)
+    an = analyze(Ac, HyluOptions(force_mode="rowrow", bulk_min_width=bmw))
+    plan = an.plan
+    sched = structure.get_bucket_schedule(plan, bulk_min_width=bmw)
+    chunks = sched.scan_chunks
+    n_win = [len(c.windows) for c in chunks]
+    lens = [int(c.lv1 - c.lv0) for c in chunks]
+
+    # the case exercises what it names
+    assert chunks
+    if case in ("many-windows", "vmap-k3"):
+        assert max(n_win) >= 3
+        assert structure.bucket_stats(
+            plan, bulk_min_width=bmw)["tail_windowed_level_share"] > 0
+    if case == "one-window":
+        assert all(w == 1 for w in n_win) and max(lens) > 1
+    if case == "dup-writes":
+        assert max(n_win) >= 3
+        dup = False
+        for c in chunks:
+            for (l0, l1) in c.windows:
+                if l1 - l0 > 1:
+                    for w in c.write_idx[l0:l1]:
+                        real = w[w < c.n_slots]
+                        dup |= len(np.unique(real)) < len(real)
+        assert dup
+    if case == "tiny-pivot":
+        assert not sched.steps and max(n_win) >= 2
+
+    m = _m_values(an, Ac)
+    eps = an.opts.perturb_eps
+    datas = [m.data * (1.0 + 0.25 * i) for i in range(k)]
+    refs = [ref_engine.factor(plan, CSR(m.n, m.indptr, m.indices, d),
+                              perturb_eps=eps) for d in datas]
+    if case == "tiny-pivot":
+        assert refs[0].n_perturb >= 1
+    tol = 1e-8 if case == "tiny-pivot" else 1e-10
+    fb_fn = make_factor_fn(plan, bulk_min_width=bmw)
+    fu_fn = jax.jit(make_factor_fn(plan, schedule="unrolled"))
+    if k > 1:
+        fbs = jax.jit(jax.vmap(fb_fn))(jnp.asarray(np.stack(datas)))
+        fbs = [jax.tree.map(lambda x, i=i: x[i], fbs) for i in range(k)]
+    else:
+        fbs = [jax.jit(fb_fn)(jnp.asarray(datas[0]))]
+    for i, (fb, fr) in enumerate(zip(fbs, refs)):
+        fu = fu_fn(jnp.asarray(datas[i]))
+        for name, f in (("windowed", fb), ("unrolled", fu)):
+            tag = (case, i, name)
+            assert np.abs(np.asarray(f.vals) - fr.vals).max() < tol, tag
+            assert np.array_equal(np.asarray(f.inode_perm),
+                                  fr.inode_perm), tag
+            assert int(f.n_perturb) == fr.n_perturb, tag
+        assert np.abs(np.asarray(fb.vals) - np.asarray(fu.vals)).max() < tol

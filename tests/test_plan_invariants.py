@@ -87,20 +87,29 @@ def test_plan_invariants(seed, n, density, mode, amalg_fill_tol):
 @given(st.integers(0, 10_000), st.integers(12, 70), st.floats(0.04, 0.22),
        st.sampled_from(["rowrow", "hybrid", "supernodal"]),
        st.sampled_from([2, 8]),
-       st.sampled_from([0.0, 1.0]))
+       st.sampled_from([0.0, 1.0]),
+       st.sampled_from([8, 64, None]))
 def test_bucket_schedule_invariants(seed, n, density, mode, bmw,
-                                    amalg_fill_tol):
+                                    amalg_fill_tol, window_cap):
     """The level-bucketed factor schedule must be a complete, non-
     overlapping re-grouping of the plan: every node's internal LU appears
     exactly once (diag bucket, panel bucket, sequential list, or scanned
     level), every edge exactly once (unrolled edge bucket or scan chunk),
     all padded indices point at the sentinel slots, and all multiplier
-    scatter positions within a level are disjoint."""
-    from repro.core.structure import build_bucket_schedule
+    scatter positions within a level are disjoint.  The scanned tail's
+    windows partition each chunk's levels, and each window's buffer holds
+    exactly the slots its levels touch (``window_cap`` None keeps the
+    module's tail window cap)."""
+    from unittest import mock
+
+    from repro.core import structure
 
     an = _analysis(seed, n, density, mode, amalg_fill_tol)
     plan = an.plan
-    sched = build_bucket_schedule(plan, bulk_min_width=bmw)
+    cap = window_cap or structure.TAIL_WINDOW_SLOTS
+    with mock.patch.object(structure, "TAIL_WINDOW_SLOTS", cap):
+        sched = structure.build_bucket_schedule(plan, bulk_min_width=bmw)
+        stats = structure.bucket_stats(plan, bulk_min_width=bmw)
     total = sched.total_slots
     sentinels = {sched.zero_slot, sched.one_slot, sched.scratch_slot}
 
@@ -125,7 +134,7 @@ def test_bucket_schedule_invariants(seed, n, density, mode, bmw,
     # --- edges covered exactly once ---------------------------------------
     n_edges_plan = sum(len(nd.edges) for nd in plan.nodes)
     n_edges_steps = sum(len(eb.srcs) for s in sched.steps for eb in s.edges)
-    n_edges_scan = sum(int((c.x_idx < total).sum())
+    n_edges_scan = sum(int((c.x_idx < c.n_slots).sum())
                        for c in sched.scan_chunks)
     assert n_edges_steps + n_edges_scan == n_edges_plan
 
@@ -157,6 +166,68 @@ def test_bucket_schedule_invariants(seed, n, density, mode, bmw,
             expect = sum(plan.nodes[t].nr * plan.nodes[t].width
                          for t in pb.nids)
             assert len(real) == expect
+
+    # --- scanned tail windows ----------------------------------------------
+    win_slots, windowed = [], 0
+    for c in sched.scan_chunks:
+        g = c.n_slots
+        zero, one, scr = g, g + 1, g + 2
+        w = c.windows
+        # every scanned level lies in exactly one window
+        assert w[0, 0] == 0 and w[-1, 1] == c.lv1 - c.lv0
+        assert np.all(w[:, 1] > w[:, 0]) and np.all(w[1:, 0] == w[:-1, 1])
+        assert c.gidx.shape[0] == len(w)
+        for (l0, l1), gidx in zip(w, c.gidx):
+            # the buffer's own zero / one / scratch entries gather the
+            # global sentinels
+            assert list(gidx[g:]) == [sched.zero_slot, sched.one_slot,
+                                      sched.scratch_slot]
+            gidx = gidx[:g]
+            real_g = gidx[gidx < total]
+            # real slots first, sorted and unique; pads → global scratch
+            assert np.all(gidx[len(real_g):] == sched.scratch_slot)
+            assert np.all(np.diff(real_g) > 0)
+            touched = []
+            for arr, allowed in ((c.dsl, {one}), (c.x_idx, {zero}),
+                                 (c.src_idx[:, :, :1], {one}),
+                                 (c.src_idx[:, :, 1:], {zero}),
+                                 (c.write_idx, {scr})):
+                blk = arr[l0:l1]
+                assert blk.min() >= 0 and blk.max() < g + 3
+                # pads point at the window's own zero / one / scratch
+                assert set(np.unique(blk[blk >= g])) <= allowed
+                # real positions never land on a gidx pad
+                assert np.all(blk[blk < g] < len(real_g))
+                touched.append(gidx[blk[blk < g]])
+            # the buffer covers exactly the real slots its levels touch
+            assert np.array_equal(np.unique(np.concatenate(touched)),
+                                  real_g)
+            # the diagonals are the levels' own pivot slots
+            for lv in range(c.lv0 + l0, c.lv0 + l1):
+                d = c.dsl[lv - c.lv0]
+                expect = plan.row_perm_slots[
+                    [plan.nodes[int(t)].r0 for t in plan.levels[lv]]]
+                assert np.array_equal(np.sort(gidx[d[d < g]]),
+                                      np.sort(expect))
+            if l1 - l0 > 1:
+                assert len(real_g) <= cap
+                windowed += int(l1 - l0)
+            win_slots.append(len(real_g))
+        # a window stops only where its next level would pass the cap
+        for (_, l1), gidx in zip(w[:-1], c.gidx[:-1, :g]):
+            nxt = [arr[l1][arr[l1] < g] for arr in (c.dsl, c.x_idx,
+                                                    c.src_idx, c.write_idx)]
+            nxt = c.gidx[np.searchsorted(w[:, 0], l1)][
+                np.concatenate([a.ravel() for a in nxt])]
+            assert len(np.union1d(gidx[gidx < total], nxt)) > cap
+    # the schedule's counters agree with the windows
+    n_scanned = sum(c.lv1 - c.lv0 for c in sched.scan_chunks)
+    assert stats["n_tail_windows"] == len(win_slots)
+    assert stats["tail_window_slots_max"] == max(win_slots, default=0)
+    assert np.isclose(stats["tail_window_slots_mean"],
+                      np.mean(win_slots) if win_slots else 0.0)
+    assert np.isclose(stats["tail_windowed_level_share"],
+                      windowed / max(n_scanned, 1))
 
 
 @settings(max_examples=8, deadline=None)

@@ -3,7 +3,8 @@ against the plain reference, and the result line.
 
 The cell, its configuration, its traffic mix and its metrics are read from
 ``BENCHMARK.json`` and the files it names; nothing here is specific to one
-of them.  A traffic module (``traffic/<kind>.py``) provides ``Traffic``::
+of them.  A configuration's pattern generator (``generators/<name>.py``)
+provides ``generate(**args)``, its matrix.  A traffic module (``traffic/<kind>.py``) provides ``Traffic``::
 
     Traffic(env, params, seed, seconds, traced)   set-up: inputs, warm-up
     .window(seconds, traced)                      the measured window

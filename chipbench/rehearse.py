@@ -4,6 +4,13 @@ v5e on a host without one, and print their compile seconds and
 ``memory_analysis()`` bytes.  Nothing runs, so this gives no device time.
 
     JAX_PLATFORMS=cpu python3 chipbench/rehearse.py --workload circuit-sweep
+
+``--config`` merges into the cell's configuration, as in ``sweeps.py``:
+a configuration that no cell runs yet is rehearsed through a cell of the
+same traffic, for example
+
+    --workload fem2d-sweep --config '{"pattern": {"generator": "fem3d",
+        "args": {"nx": 22, "ny": 22, "nz": 22, "seed": 931}}}'
 """
 import argparse
 import json
@@ -28,6 +35,7 @@ def main(argv=None):
     ap.add_argument("--workload", required=True)
     ap.add_argument("--k", type=int, default=None,
                     help="systems per program (default: the cell's)")
+    ap.add_argument("--config", help="JSON merged into the configuration")
     args = ap.parse_args(argv)
 
     import jax
@@ -44,9 +52,10 @@ def main(argv=None):
     jax.config.update("jax_enable_x64", True)
     jax.config.update("jax_enable_compilation_cache", False)
     cell = harness.load_cell(args.workload)
+    config = harness._merge(cell.config, json.loads(args.config or "{}"))
     k = args.k or int(cell.traffic["systems_per_step"])
-    opts = harness.solver_options(cell.config)
-    a_sp = patterns.build(cell.config["pattern"])
+    opts = harness.solver_options(config)
+    a_sp = patterns.build(config["pattern"])
     a = CSR.from_scipy(a_sp)
     an = PlanCache(directory=str(harness.PLAN_CACHE_DIR)).get_or_analyze(
         a, opts)
@@ -58,7 +67,8 @@ def main(argv=None):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    out = {"workload": args.workload, "k": k, "mode": an.choice.mode}
+    out = {"workload": args.workload, "pattern": config["pattern"], "k": k,
+           "mode": an.choice.mode, "n": a.n, "nnz": a.nnz}
     values = sds((k, a.nnz), vdt)
     t0 = time.perf_counter()
     lowered = eng.refactor_batched.lower(values)

@@ -4,6 +4,7 @@ for each fault the cells can have, on small sizes on the CPU.
 Each run drives the whole harness (set-up, window, reference check) with
 the device check off; the faults are planted underneath, in the program's
 own entry points, where the answers are produced."""
+import copy
 import json
 
 import numpy as np
@@ -11,14 +12,42 @@ import pytest
 
 from chipbench import harness
 
-SMALL = {
-    "circuit-sweep": ({"pattern": {"args": {"n": 200}}},
-                      {"systems_per_step": 4, "samples_per_step": 4}),
-    "fem2d-sweep": ({"pattern": {"args": {"nx": 10, "ny": 10}}},
-                    {"systems_per_step": 4, "samples_per_step": 4}),
-}
 SPEC = harness.load_json(harness.ROOT / "BENCHMARK.json")
 CONTROL = {"precision": {"refine_dtype": "float32"}}
+# A configuration that no cell of BENCHMARK.json names, brought in as a
+# configuration file of its own (its generator has a file of its own too):
+# the harness takes it with no edit to an existing file.
+ADDED_CONFIG = {
+    "name": "fem3d",
+    "pattern": {"generator": "fem3d",
+                "args": {"nx": 22, "ny": 22, "nz": 22, "seed": 931}},
+    # 8 x 8 x 8 routes hybrid, as 22 x 22 x 22 does (6 x 6 x 6: rowrow)
+    "rehearsal": {"config": {"pattern": {"args": {"nx": 8, "ny": 8,
+                                                  "nz": 8}}},
+                  "traffic": {"systems_per_step": 4, "samples_per_step": 4}},
+    "precision": {"factor_dtype": "float32", "refine_dtype": "float64"},
+    "options": {"fp64_fallback": False, "retry_max": 0},
+    "limits": {"residual": 1e-12, "error_ratio": 1.0},
+}
+ADDED_CELL = {"name": "fem3d-sweep", "config": "fem3d",
+              "traffic": "sweep_k32", "chips": 1,
+              "why": "a configuration added from new files alone"}
+CELLS = [w["name"] for w in SPEC["workloads"]] + [ADDED_CELL["name"]]
+
+
+@pytest.fixture(scope="module")
+def spec(tmp_path_factory):
+    """BENCHMARK.json with the added configuration and its cell, which
+    reports every sweep metric, in memory; the configuration's file is
+    written outside the checkout."""
+    path = tmp_path_factory.mktemp("configs") / "fem3d.json"
+    path.write_text(json.dumps(ADDED_CONFIG))
+    out = copy.deepcopy(SPEC)
+    out["configs"].append({"name": "fem3d", "file": str(path)})
+    out["workloads"].append(ADDED_CELL)
+    for m in out["end_to_end"] + out["per_layer"]:
+        m.get("workloads", []).append(ADDED_CELL["name"])
+    return out
 
 
 @pytest.fixture(scope="module")
@@ -26,17 +55,18 @@ def memo():
     return {}
 
 
-def run(cell, memo, config=None, seed=11, seconds=1.5):
-    cfg, trf = SMALL[cell]
+def run(cell, spec, memo, config=None, seed=11, seconds=1.5):
+    """One run of the cell at its configuration's CPU rehearsal size."""
+    small = harness.load_cell(cell, spec).config["rehearsal"]
     return harness.run_cell(
         cell, seed, seconds, False, device_check=False, compile_cache=False,
-        config_over=harness._merge(cfg, config), traffic_over=trf, memo=memo,
-        spec=SPEC)
+        config_over=harness._merge(small["config"], config),
+        traffic_over=small["traffic"], memo=memo, spec=spec)
 
 
-@pytest.mark.parametrize("cell", list(SMALL))
-def test_sound_run_is_correct(cell, memo):
-    r = run(cell, memo)
+@pytest.mark.parametrize("cell", CELLS)
+def test_sound_run_is_correct(cell, spec, memo):
+    r = run(cell, spec, memo)
     assert r["correct"], r["checks"]
     assert r["failed"] == 0 and r["attempted"] >= 4
     assert list(r)[-1] == "checks"
@@ -44,11 +74,11 @@ def test_sound_run_is_correct(cell, memo):
     json.dumps(r)
 
 
-@pytest.mark.parametrize("cell", list(SMALL))
-def test_control_is_not_correct(cell, memo):
+@pytest.mark.parametrize("cell", CELLS)
+def test_control_is_not_correct(cell, spec, memo):
     """The control: the configuration's float64 refinement lowered to
     float32, the step a later change would be tempted by."""
-    r = run(cell, memo, config=CONTROL)
+    r = run(cell, spec, memo, config=CONTROL)
     assert not r["correct"]
     c = r["checks"]
     assert c["residual"]["value"] > 3 * c["residual"]["limit"]
@@ -60,8 +90,8 @@ def _alter_one(x):
     return x
 
 
-@pytest.mark.parametrize("cell", list(SMALL))
-def test_sweep_answer_altered(cell, memo, monkeypatch):
+@pytest.mark.parametrize("cell", CELLS)
+def test_sweep_answer_altered(cell, spec, memo, monkeypatch):
     from repro.core import batched
 
     real = batched.solve_batched
@@ -71,11 +101,11 @@ def test_sweep_answer_altered(cell, memo, monkeypatch):
         return _alter_one(x), info
 
     monkeypatch.setattr(batched, "solve_batched", altered)
-    assert not run(cell, memo)["correct"]
+    assert not run(cell, spec, memo)["correct"]
 
 
-@pytest.mark.parametrize("cell", list(SMALL))
-def test_sweep_state_left_unchanged(cell, memo, monkeypatch):
+@pytest.mark.parametrize("cell", CELLS)
+def test_sweep_state_left_unchanged(cell, spec, memo, monkeypatch):
     """The refactor returns its first state again: every later step solves
     the warm-up's matrices, not its own fresh ones."""
     from repro.core import batched
@@ -89,11 +119,11 @@ def test_sweep_state_left_unchanged(cell, memo, monkeypatch):
         return first[0]
 
     monkeypatch.setattr(batched, "factor_batched", stale)
-    assert not run(cell, memo)["correct"]
+    assert not run(cell, spec, memo)["correct"]
 
 
-@pytest.mark.parametrize("cell", list(SMALL))
-def test_sweep_half_the_batch_left_out(cell, memo, monkeypatch):
+@pytest.mark.parametrize("cell", CELLS)
+def test_sweep_half_the_batch_left_out(cell, spec, memo, monkeypatch):
     """Only the first half of each batch is solved; the rest come back
     as the answers of the first half."""
     from repro.core import batched
@@ -108,4 +138,4 @@ def test_sweep_half_the_batch_left_out(cell, memo, monkeypatch):
         return x, info
 
     monkeypatch.setattr(batched, "solve_batched", half)
-    assert not run(cell, memo)["correct"]
+    assert not run(cell, spec, memo)["correct"]

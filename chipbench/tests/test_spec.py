@@ -7,6 +7,8 @@ import shutil
 import subprocess
 import sys
 
+import pytest
+
 from chipbench import harness
 
 ROOT = harness.ROOT
@@ -52,6 +54,9 @@ def test_configs_and_cells():
         assert sorted(f["reduced"]) == sorted(c["reduced"])
         for key in c["reduced"]:
             assert f["published"]["args"][key] != f["pattern"]["args"][key]
+        assert (ROOT / "chipbench" / "generators"
+                / f"{f['pattern']['generator']}.py").is_file()
+        assert set(f["rehearsal"]) == {"config", "traffic"}
     pairs = set()
     for w in SPEC["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
@@ -84,6 +89,16 @@ def test_metrics():
         names = {m["name"] for m in cell.end_to_end}
         assert "setup_s" in names and len(names) >= 2
         assert cell.per_layer
+
+
+def test_an_unknown_generator_fails_before_analysis():
+    with pytest.raises(KeyError) as e:
+        harness.run_cell("circuit-sweep", 1, 1.0, False, device_check=False,
+                         compile_cache=False,
+                         config_over={"pattern": {"generator": "no_such"}})
+    assert "no_such" in str(e.value)
+    assert all(g in str(e.value) for g in ("circuit_like", "fem2d", "fem3d",
+                                           "powergrid_like"))
 
 
 def test_refuses_to_run_without_an_accelerator(capsys):

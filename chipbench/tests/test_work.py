@@ -45,9 +45,10 @@ def test_roofline_share_takes_the_larger_bound():
 
 @pytest.mark.parametrize("n", [50, 120])
 def test_counts_match_the_symbolic_stats(n):
-    from chipbench.patterns import circuit_like
+    from chipbench import patterns
 
-    an = _analysis(circuit_like(n, seed=3))
+    an = _analysis(patterns.build({"generator": "circuit_like",
+                                   "args": {"n": n, "seed": 3}}))
     c = work.Counts.of(an)
     st = an.choice.stats
     assert c.flops == st["flops"] and c.nnz_lu == 2 * st["nnz_l"] + n

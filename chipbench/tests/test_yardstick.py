@@ -1,15 +1,18 @@
 """The copied yardstick agrees with the repository's originals: the
 pattern generators bit for bit, the reference's numbers to rounding."""
 import importlib.util
+import inspect
+import json
 import os
 
 import numpy as np
 import pytest
 
-from chipbench import patterns, reference
+from chipbench import harness, patterns, reference
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+SPEC = harness.load_json(harness.ROOT / "BENCHMARK.json")
 
 
 def _smoke():
@@ -29,29 +32,60 @@ def _same(a, b):
             and np.array_equal(a.data, b.data))
 
 
-@pytest.mark.parametrize("name,args", [
-    ("circuit_like", dict(n=10000, seed=912)),
-    ("circuit_like", dict(n=2000, seed=912)),
-    ("fem2d", dict(nx=100, ny=100, seed=930)),
-    ("fem2d", dict(nx=70, ny=70, seed=930)),
-])
+def _corpus_calls():
+    """``(generator, args)`` of each corpus entry, read by calling the
+    entries' `gen` with the generators replaced by a recorder."""
+    from benchmarks import corpus, matrices
+
+    calls = []
+    saved = {name: fn for name, fn in vars(corpus).items()
+             if inspect.isfunction(fn) and fn.__module__ == matrices.__name__}
+    try:
+        for name, fn in saved.items():
+            sig = inspect.signature(fn)
+
+            def record(*a, _name=name, _sig=sig, **kw):
+                calls.append((_name, dict(_sig.bind(*a, **kw).arguments)))
+            setattr(corpus, name, record)
+        for e in corpus.corpus():
+            e.gen()
+    finally:
+        for name, fn in saved.items():
+            setattr(corpus, name, fn)
+    return calls
+
+
+def _generator_cases():
+    """Every generator file with a namesake in ``benchmarks/matrices.py``,
+    at the args of each corpus entry that uses it, and at a second size of
+    each of the benchmark's own two."""
+    from benchmarks import matrices
+
+    extra = [("circuit_like", dict(n=2000, seed=912)),
+             ("fem2d", dict(nx=70, ny=70, seed=930))]
+    return [(name, args) for name, args in _corpus_calls() + extra
+            if (patterns.GENERATORS / f"{name}.py").is_file()
+            and hasattr(matrices, name)]
+
+
+@pytest.mark.parametrize("name,args", _generator_cases(),
+                         ids=lambda v: v if isinstance(v, str) else
+                         "-".join(map(str, v.values())))
 def test_generators_reproduce_the_originals(name, args):
     from benchmarks import matrices
 
-    assert _same(getattr(patterns, name)(**args),
+    assert _same(patterns.build({"generator": name, "args": args}),
                  getattr(matrices, name)(**args))
 
 
-@pytest.mark.parametrize("file", ["circuit.json", "fem2d.json"])
+@pytest.mark.parametrize("file", [c["file"] for c in SPEC["configs"]])
 def test_configs_reproduce_the_corpus(file):
     """Each configuration's stand-in is its corpus entry's matrix, and its
     pattern differs from the stand-in only in the keys it lists as
     reduced."""
-    import json
-
     from benchmarks import corpus
 
-    cfg = json.load(open(os.path.join(_ROOT, "chipbench", "configs", file)))
+    cfg = json.load(open(os.path.join(_ROOT, file)))
     stand_in = cfg["published"]["stand_in"]
     e = {c.name: c for c in corpus.corpus()}[stand_in["corpus_entry"]]
     a, _, meta = corpus.load_entry(e, allow_download=False)
@@ -65,7 +99,8 @@ def test_configs_reproduce_the_corpus(file):
 
 def test_perturbed_values_and_oracle_agree_with_chip_smoke():
     smoke = _smoke()
-    a = patterns.circuit_like(300, seed=5)
+    a = patterns.build({"generator": "circuit_like",
+                       "args": {"n": 300, "seed": 5}})
     v1 = reference.perturbed_values(a, 3, np.random.default_rng(9))
     v2 = smoke.perturbed_values(a, 3, np.random.default_rng(9))
     assert np.array_equal(v1, v2)
